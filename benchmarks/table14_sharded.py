@@ -16,15 +16,12 @@ size: OTPS (wall), virtual-time makespan, mean per-step wall time, and the
 per-step overhead vs the unsharded engine. Rows persist to
 ``results/table14_sharded.csv``.
 
-Needs >= 8 jax devices; when the current process was initialised without
-them (e.g. via ``benchmarks/run.py``), it re-execs itself in a subprocess
-with ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` — the same
-forced-host-device setup as CI's tier1-multidevice lane.
+Needs >= 8 jax devices. On a CPU host, start the process with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (the same
+forced-host-device setup as CI's tier1-multidevice lane); the flag only
+takes effect before JAX first initialises, so the table raises instead of
+starting a child once JAX is up.
 """
-import os
-import subprocess
-import sys
-
 import numpy as np
 
 MESH_SIZES = (1, 2, 4, 8)
@@ -45,33 +42,10 @@ def _serve_workload(eng, prompts, budgets, arrivals):
 def run(epochs=15, n_requests=16, max_new=20, mean_gap=0.5):
     import jax
     if jax.device_count() < max(MESH_SIZES):
-        if os.environ.get("_TABLE14_CHILD"):
-            raise RuntimeError(
-                f"forced host devices did not take effect (jax sees "
-                f"{jax.device_count()}); not re-execing again")
-        # jax is already initialised single-device: re-exec with forced
-        # host devices (the flag only takes effect before first jax use).
-        # Any pre-existing force-count flag is REPLACED, not shadowed —
-        # XLA lets the last duplicate win, which would loop forever.
-        env = dict(os.environ)
-        flags = [f for f in env.get("XLA_FLAGS", "").split()
-                 if not f.startswith(
-                     "--xla_force_host_platform_device_count")]
-        flags.append(
+        raise RuntimeError(
+            f"table14 needs {max(MESH_SIZES)} devices but jax sees "
+            f"{jax.device_count()}; on a CPU host rerun with XLA_FLAGS="
             f"--xla_force_host_platform_device_count={max(MESH_SIZES)}")
-        env["XLA_FLAGS"] = " ".join(flags)
-        env["_TABLE14_CHILD"] = "1"
-        env["PYTHONPATH"] = (os.path.join(os.path.dirname(__file__), "..",
-                                          "src") + os.pathsep
-                             + env.get("PYTHONPATH", ""))
-        ret = subprocess.run(
-            [sys.executable, "-m", "benchmarks.table14_sharded",
-             f"--epochs={epochs}", f"--n-requests={n_requests}",
-             f"--max-new={max_new}", f"--mean-gap={mean_gap}"],
-            cwd=os.path.join(os.path.dirname(__file__), ".."), env=env)
-        if ret.returncode:
-            raise RuntimeError("table14 subprocess failed")
-        return
 
     from benchmarks.common import (get_corpus, longtail_budgets, get_target,
                                    row, train_drafter, write_results_csv)

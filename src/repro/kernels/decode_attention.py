@@ -26,7 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.kernels.flash_attention import (softmax_finish, softmax_init,
+                                           softmax_scratch, softmax_update)
 
 
 def _decode_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
@@ -36,38 +37,24 @@ def _decode_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(kj == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        softmax_init(m_scr, l_scr, acc_scr)
 
-    q = q_ref[0].astype(jnp.float32)             # (T, hd)
-    k = k_ref[0].astype(jnp.float32)             # (block_k, hd)
-    v = v_ref[0].astype(jnp.float32)
+    q = q_ref[...].astype(jnp.float32)           # (T, hd)
+    k = k_ref[...].astype(jnp.float32)           # (block_k, hd)
+    v = v_ref[...].astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
 
-    qp = qpos_ref[0][:, None]                    # (T, 1)
-    kp = kpos_ref[0][None, :]                    # (1, block_k)
+    qp = qpos_ref[...]                           # (T, 1)
+    kp = kpos_ref[...]                           # (1, block_k)
     ok = (kp <= qp) & (kp >= 0)
     if window > 0:
         ok &= (qp - kp) < window
-    s = jnp.where(ok, s, NEG_INF)
-
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * alpha + p.sum(axis=1)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
+    softmax_update(s, ok, v, m_scr, l_scr, acc_scr)
 
     @pl.when(kj == n_kv_blocks - 1)
     def _done():
-        l = l_scr[...]
-        out = acc_scr[...] / jnp.maximum(l, 1e-30)[:, None]
-        out = jnp.where((l > 0)[:, None], out, 0.0)
-        o_ref[0] = out.astype(o_ref.dtype)
+        o_ref[...] = softmax_finish(l_scr, acc_scr).astype(o_ref.dtype)
 
 
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -93,23 +80,20 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                           n_kv_blocks=n_kv_blocks),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, T), lambda b, h, j: (b, 0)),
-            pl.BlockSpec((1, block_k), lambda b, h, j: (b, j)),
-            pl.BlockSpec((1, None, T, hd), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, None, block_k, hd),
+            pl.BlockSpec((None, T, 1), lambda b, h, j: (b, 0, 0)),
+            pl.BlockSpec((None, 1, block_k), lambda b, h, j: (b, 0, j)),
+            pl.BlockSpec((None, None, T, hd), lambda b, h, j: (b, h, 0, 0)),
+            pl.BlockSpec((None, None, block_k, hd),
                          lambda b, h, j, G=G: (b, h // G, j, 0)),
-            pl.BlockSpec((1, None, block_k, hd),
+            pl.BlockSpec((None, None, block_k, hd),
                          lambda b, h, j, G=G: (b, h // G, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, None, T, hd), lambda b, h, j: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((None, None, T, hd),
+                               lambda b, h, j: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, T, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((T,), jnp.float32),
-            pltpu.VMEM((T,), jnp.float32),
-            pltpu.VMEM((T, hd), jnp.float32),
-        ],
+        scratch_shapes=softmax_scratch(T, hd),
         interpret=interpret,
-    )(q_positions, k_positions, qt, kt, vt)
+    )(q_positions[:, :, None], k_positions[:, None, :], qt, kt, vt)
     return out.transpose(0, 2, 1, 3)
 
 
@@ -125,41 +109,27 @@ def _paged_kernel(bt_ref, qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        softmax_init(m_scr, l_scr, acc_scr)
 
-    q = q_ref[0].astype(jnp.float32)             # (T, hd)
+    q = q_ref[...].astype(jnp.float32)           # (T, hd)
     k = k_ref[...].astype(jnp.float32)           # (page, hd)
     v = v_ref[...].astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
 
-    qp = qpos_ref[0][:, None]                    # (T, 1)
-    kp = kpos_ref[...][None, :]                  # (1, page)
+    qp = qpos_ref[...]                           # (T, 1)
+    kp = kpos_ref[...]                           # (1, page)
     ok = (kp <= qp) & (kp >= 0)
     if window > 0:
         ok &= (qp - kp) < window
     # unallocated page: the index map clamped it to page 0, whose positions
     # could alias a *live* request's — mask the whole contribution
     ok &= bt_ref[b, j] >= 0
-    s = jnp.where(ok, s, NEG_INF)
-
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * alpha + p.sum(axis=1)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
+    softmax_update(s, ok, v, m_scr, l_scr, acc_scr)
 
     @pl.when(j == n_pages - 1)
     def _done():
-        l = l_scr[...]
-        out = acc_scr[...] / jnp.maximum(l, 1e-30)[:, None]
-        out = jnp.where((l > 0)[:, None], out, 0.0)
-        o_ref[0] = out.astype(o_ref.dtype)
+        o_ref[...] = softmax_finish(l_scr, acc_scr).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
@@ -179,6 +149,9 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
 
     qt = q.transpose(0, 2, 1, 3)                 # (B, H, T, hd)
     grid = (B, H, nb)
+    # the pools enter as (NP, page, KV*hd), a free reshape: a (page, hd)
+    # block at column block h // G is one KV head of one page, and both of
+    # its minor dims are tile-aligned (a squeezed KV axis would not be)
 
     def page_idx(b, h, j, bt):
         return jnp.maximum(bt[b, j], 0)
@@ -187,24 +160,21 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, T), lambda b, h, j, bt: (b, 0)),
-            pl.BlockSpec((None, page),
-                         lambda b, h, j, bt: (page_idx(b, h, j, bt), 0)),
-            pl.BlockSpec((1, None, T, hd), lambda b, h, j, bt: (b, h, 0, 0)),
-            pl.BlockSpec((None, page, None, hd),
+            pl.BlockSpec((None, T, 1), lambda b, h, j, bt: (b, 0, 0)),
+            pl.BlockSpec((None, 1, page),
+                         lambda b, h, j, bt: (page_idx(b, h, j, bt), 0, 0)),
+            pl.BlockSpec((None, None, T, hd),
+                         lambda b, h, j, bt: (b, h, 0, 0)),
+            pl.BlockSpec((None, page, hd),
                          lambda b, h, j, bt, G=G:
-                         (page_idx(b, h, j, bt), 0, h // G, 0)),
-            pl.BlockSpec((None, page, None, hd),
+                         (page_idx(b, h, j, bt), 0, h // G)),
+            pl.BlockSpec((None, page, hd),
                          lambda b, h, j, bt, G=G:
-                         (page_idx(b, h, j, bt), 0, h // G, 0)),
+                         (page_idx(b, h, j, bt), 0, h // G)),
         ],
-        out_specs=pl.BlockSpec((1, None, T, hd),
+        out_specs=pl.BlockSpec((None, None, T, hd),
                                lambda b, h, j, bt: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((T,), jnp.float32),
-            pltpu.VMEM((T,), jnp.float32),
-            pltpu.VMEM((T, hd), jnp.float32),
-        ],
+        scratch_shapes=softmax_scratch(T, hd),
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, scale=scale, window=window,
@@ -212,5 +182,6 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         grid_spec=gs,
         out_shape=jax.ShapeDtypeStruct((B, H, T, hd), q.dtype),
         interpret=interpret,
-    )(block_table, q_positions, pos_pool, qt, k_pool, v_pool)
+    )(block_table, q_positions[:, :, None], pos_pool[:, None, :], qt,
+      k_pool.reshape(NP, page, KV * hd), v_pool.reshape(NP, page, KV * hd))
     return out.transpose(0, 2, 1, 3)

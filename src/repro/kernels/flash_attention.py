@@ -10,8 +10,10 @@ Grid: (batch, q_heads, Sq/block_q, Skv/block_k); the innermost grid
 dimension iterates KV blocks for a fixed query tile, accumulating into
 scratch, and writes the output tile on the last iteration.
 
-Validated on CPU with interpret=True against kernels/ref.py (the same
-math as models/layers.blocked_attention).
+The online-softmax state helpers (``softmax_*``) are shared with the MTP
+and decode kernels. Validated on CPU with interpret=True against
+kernels/ref.py (the same math as models/layers.blocked_attention), and
+compiled for a described v5e by tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -23,6 +25,45 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANES = 128                        # vector lanes of one TPU vreg
+
+
+def softmax_scratch(rows: int, hd: int) -> list:
+    """VMEM scratch for the online softmax of ``rows`` query rows: running
+    max and denominator as (rows, 128) lane-replicated tiles, plus the
+    (rows, hd) float32 accumulator. Mosaic lays out 2-D tiles only, so the
+    per-row statistics are kept lane-broadcast rather than 1-D."""
+    return [pltpu.VMEM((rows, LANES), jnp.float32),
+            pltpu.VMEM((rows, LANES), jnp.float32),
+            pltpu.VMEM((rows, hd), jnp.float32)]
+
+
+def softmax_init(m_scr, l_scr, acc_scr):
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+
+def softmax_update(s, ok, v, m_scr, l_scr, acc_scr):
+    """Fold one (rows, block_k) masked score tile and its (block_k, hd)
+    values into the running softmax state."""
+    s = jnp.where(ok, s, NEG_INF)
+    m_prev = m_scr[...]                                   # (rows, LANES)
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+    # mask p explicitly: fully-masked rows would see exp(-inf - -inf) = 1
+    p = jnp.where(ok, jnp.exp(s - m_new[:, :1]), 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    l_scr[...] = l_scr[...] * alpha + p.sum(axis=1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha[:, :1] + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    m_scr[...] = m_new
+
+
+def softmax_finish(l_scr, acc_scr):
+    """Normalized output rows; rows that attended nothing are zero."""
+    l = l_scr[...][:, :1]
+    out = acc_scr[...] / jnp.maximum(l, 1e-30)
+    return jnp.where(l > 0, out, 0.0)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
@@ -34,9 +75,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(kj == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        softmax_init(m_scr, l_scr, acc_scr)
 
     q = q_ref[...].astype(jnp.float32)            # (block_q, hd)
     k = k_ref[...].astype(jnp.float32)            # (block_k, hd)
@@ -56,24 +95,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         ok &= q_pos >= k_pos
     if window > 0:
         ok &= (q_pos - k_pos) < window
-    s = jnp.where(ok, s, NEG_INF)
-
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    # mask p explicitly: fully-masked rows would see exp(-inf - -inf) = 1
-    p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * alpha + p.sum(axis=1)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
+    softmax_update(s, ok, v, m_scr, l_scr, acc_scr)
 
     @pl.when(kj == n_kv_blocks - 1)
     def _done():
-        l = l_scr[...]
-        out = acc_scr[...] / jnp.maximum(l, 1e-30)[:, None]
-        out = jnp.where((l > 0)[:, None], out, 0.0)
-        o_ref[...] = out.astype(o_ref.dtype)
+        o_ref[...] = softmax_finish(l_scr, acc_scr).astype(o_ref.dtype)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -116,11 +142,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out_specs=pl.BlockSpec((None, None, block_q, hd),
                                lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q, hd), jnp.float32),
-        ],
+        scratch_shapes=softmax_scratch(block_q, hd),
         interpret=interpret,
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

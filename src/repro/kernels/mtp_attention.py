@@ -14,7 +14,7 @@ core/masks.py and is what Table-2 benchmarks compare against).
 Padding (depth = -1) attends nothing; its output rows are zeroed.
 
 Grid and dataflow mirror flash_attention.py; the metadata vectors ride in
-as (block,)-tiled VMEM operands.
+as (block_q, 1) column and (1, block_k) row VMEM tiles.
 """
 from __future__ import annotations
 
@@ -23,21 +23,18 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.kernels.flash_attention import (softmax_finish, softmax_init,
+                                           softmax_scratch, softmax_update)
 
 
 def _mtp_kernel(qd_ref, qp_ref, kd_ref, kp_ref, q_ref, k_ref, v_ref, o_ref,
-                m_scr, l_scr, acc_scr, *, scale: float, block_q: int,
-                block_k: int, n_kv_blocks: int):
+                m_scr, l_scr, acc_scr, *, scale: float, n_kv_blocks: int):
     kj = pl.program_id(3)
 
     @pl.when(kj == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        softmax_init(m_scr, l_scr, acc_scr)
 
     q = q_ref[...].astype(jnp.float32)
     k = k_ref[...].astype(jnp.float32)
@@ -45,33 +42,19 @@ def _mtp_kernel(qd_ref, qp_ref, kd_ref, kp_ref, q_ref, k_ref, v_ref, o_ref,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
 
-    qg = qd_ref[...][:, None]          # (block_q, 1) depths
-    qp = qp_ref[...][:, None]          # rope positions
-    kg = kd_ref[...][None, :]          # (1, block_k)
-    kp = kp_ref[...][None, :]
+    qg = qd_ref[...]                   # (block_q, 1) depths
+    qp = qp_ref[...]                   # rope positions
+    kg = kd_ref[...]                   # (1, block_k)
+    kp = kp_ref[...]
     anchor_q = qp - qg
     anchor_k = kp - kg
     ok = ((kg == 0) & (kp <= anchor_q)) | ((anchor_k == anchor_q) & (kg <= qg))
     ok &= (qg >= 0) & (kg >= 0)
-    s = jnp.where(ok, s, NEG_INF)
-
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    # explicit mask on p: fully-masked rows would otherwise see
-    # exp(NEG_INF - NEG_INF) = 1
-    p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * alpha + p.sum(axis=1)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
+    softmax_update(s, ok, v, m_scr, l_scr, acc_scr)
 
     @pl.when(kj == n_kv_blocks - 1)
     def _done():
-        l = l_scr[...]
-        out = acc_scr[...] / jnp.maximum(l, 1e-30)[:, None]
-        out = jnp.where((l > 0)[:, None], out, 0.0)
-        o_ref[...] = out.astype(o_ref.dtype)
+        o_ref[...] = softmax_finish(l_scr, acc_scr).astype(o_ref.dtype)
 
 
 def mtp_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -92,15 +75,15 @@ def mtp_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     vt = v.transpose(0, 2, 1, 3)
     grid = (B, H, M // block_q, n_kv_blocks)
 
+    # metadata enters twice: as (M, 1) columns for the query rows and as
+    # (1, M) rows for the key columns, so the mask is a plain 2-D broadcast
+    col = pl.BlockSpec((block_q, 1), lambda b, h, i, j: (i, 0))
+    row = pl.BlockSpec((1, block_k), lambda b, h, i, j: (0, j))
     out = pl.pallas_call(
-        functools.partial(_mtp_kernel, scale=scale, block_q=block_q,
-                          block_k=block_k, n_kv_blocks=n_kv_blocks),
+        functools.partial(_mtp_kernel, scale=scale, n_kv_blocks=n_kv_blocks),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_q,), lambda b, h, i, j: (i,)),
-            pl.BlockSpec((block_q,), lambda b, h, i, j: (i,)),
-            pl.BlockSpec((block_k,), lambda b, h, i, j: (j,)),
-            pl.BlockSpec((block_k,), lambda b, h, i, j: (j,)),
+            col, col, row, row,
             pl.BlockSpec((None, None, block_q, hd),
                          lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((None, None, block_k, hd),
@@ -111,11 +94,7 @@ def mtp_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         out_specs=pl.BlockSpec((None, None, block_q, hd),
                                lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, M, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q, hd), jnp.float32),
-        ],
+        scratch_shapes=softmax_scratch(block_q, hd),
         interpret=interpret,
-    )(depth, pos, depth, pos, qt, kt, vt)
+    )(depth[:, None], pos[:, None], depth[None, :], pos[None, :], qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

@@ -28,7 +28,8 @@ from repro.launch import roofline as RL
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import (adapt_for_shape, build_prefill_step,
                                 build_serve_step, build_train_step,
-                                mesh_context, resolve_drafter)
+                                resolve_drafter)
+from repro.sharding.utils import mesh_scope
 
 
 def flatten_shardings(args: dict, extras: dict, shardings: dict,
@@ -83,7 +84,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
         args, extras if has_extras else None, shardings,
         ex_sh if has_extras else None, order)
 
-    with mesh_context(mesh):
+    with mesh_scope(mesh):
         jitted = jax.jit(fn, in_shardings=shd_vals, donate_argnums=donate)
         lowered = jitted.lower(*arg_vals)
         compiled = lowered.compile()

@@ -31,10 +31,11 @@ deterministic stubs at admission.
 ``--shard-model N`` serves model-sharded: weights and full-length KV (page
 pools included) are storage-sharded over a 1-D ``("model",)`` mesh of N
 devices, token-for-token identical to the single-device engine (see
-docs/sharding.md). On this CPU container, force host devices first:
+docs/sharding.md). On a CPU host, force host devices first:
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-        PYTHONPATH=src python -m repro.launch.serve --shard-model 8 ...
+        PYTHONPATH=src python -m repro.launch.serve --reduced \
+        --shard-model 8 ...
 """
 from __future__ import annotations
 
@@ -43,19 +44,19 @@ import argparse
 import jax
 import numpy as np
 
-from repro.checkpoint import load_pytree
-from repro.configs import DrafterConfig, get_config
-from repro.core import drafter as D
-from repro.models import get_model
+from repro.launch.build import build_engine, init_target, use_compile_cache
 from repro.serving import (Engine, EngineConfig, Request, SamplingParams,
                            Scheduler, serve_round_based)
 from repro.sharding.utils import serving_mesh
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b")
-    ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--reduced", action="store_true",
+                    help="2-layer CPU-scale config (default: published "
+                         "widths)")
     ap.add_argument("--ckpt", default="results/ckpt")
     ap.add_argument("--mode", default="parallel",
                     choices=["parallel", "ar", "none"])
@@ -141,40 +142,20 @@ def main():
             f"devices but jax sees {jax.device_count()}; on CPU set "
             "XLA_FLAGS=--xla_force_host_platform_device_count=N first")
 
-    reduced = args.reduced or jax.default_backend() != "tpu"
-    tcfg = get_config(args.arch)
-    if reduced:
-        tcfg = tcfg.reduced()
-    model = get_model(tcfg)
-    key = jax.random.PRNGKey(0)
-    tparams = model.init(key)
-
-    dcfg = dparams = None
-    if args.mode != "none":
-        dcfg = DrafterConfig(n_layers=args.layers,
-                             k_infer=args.k).resolve(tcfg)
-        tmpl = D.init_params(dcfg, tcfg, key)
-        try:
-            dparams = load_pytree(tmpl, args.ckpt, f"drafter_{args.arch}")
-            print("loaded drafter checkpoint")
-        except Exception as e:
-            print(f"no checkpoint ({e}); using random drafter")
-            dparams = tmpl
-
+    tcfg, _, tparams = init_target(args.arch, reduced=args.reduced)
     mesh = serving_mesh(args.shard_model) if args.shard_model else None
-    eng = Engine(tcfg, dcfg, tparams, dparams,
-                 EngineConfig(K=args.k, max_new_tokens=args.max_new,
-                              drafter_mode=args.mode, max_len=256,
-                              kv_layout=args.kv_layout,
-                              page_size=args.page_size,
-                              pool_pages=args.pool_pages,
-                              bucket_prefill=not args.no_bucket,
-                              kv_growth=args.kv_growth,
-                              shard_model=args.shard_model > 0, mesh=mesh,
-                              draft_sampling=args.draft_sampling,
-                              swap=args.swap,
-                              host_pool_bytes=args.host_pool_bytes),
-                 args.batch)
+    eng = build_engine(
+        tcfg, tparams,
+        EngineConfig(K=args.k, max_new_tokens=args.max_new,
+                     drafter_mode=args.mode, max_len=256,
+                     kv_layout=args.kv_layout, page_size=args.page_size,
+                     pool_pages=args.pool_pages,
+                     bucket_prefill=not args.no_bucket,
+                     kv_growth=args.kv_growth,
+                     shard_model=args.shard_model > 0, mesh=mesh,
+                     draft_sampling=args.draft_sampling, swap=args.swap,
+                     host_pool_bytes=args.host_pool_bytes),
+        args.batch, layers=args.layers, ckpt=args.ckpt)
     if mesh is not None:
         print(f"model-sharded over {mesh.shape['model']} devices "
               f"(mesh axes {mesh.axis_names}); storage-sharded weights + "
@@ -265,7 +246,7 @@ def main():
         if eng.paged:
             # the round-based baseline is a whole-batch loop (one contiguous
             # state per round) — paged states are scheduler-only
-            rb_eng = Engine(tcfg, dcfg, tparams, dparams,
+            rb_eng = Engine(tcfg, eng.dcfg, tparams, eng.dparams,
                             EngineConfig(K=args.k,
                                          max_new_tokens=args.max_new,
                                          drafter_mode=args.mode, max_len=256),

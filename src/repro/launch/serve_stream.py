@@ -268,38 +268,20 @@ def main():
         return
 
     # heavyweight imports only on the server path — the client stays light
-    import jax
-    from repro.checkpoint import load_pytree
-    from repro.configs import DrafterConfig, get_config
-    from repro.core import drafter as D
-    from repro.models import get_model
-    from repro.serving import Engine, EngineConfig
+    from repro.launch.build import build_engine, init_target, \
+        use_compile_cache
+    from repro.serving import EngineConfig
 
-    reduced = args.reduced or jax.default_backend() != "tpu"
-    tcfg = get_config(args.arch)
-    if reduced:
-        tcfg = tcfg.reduced()
-    model = get_model(tcfg)
-    key = jax.random.PRNGKey(0)
-    tparams = model.init(key)
-    dcfg = dparams = None
-    if args.mode != "none":
-        dcfg = DrafterConfig(n_layers=args.layers,
-                             k_infer=args.k).resolve(tcfg)
-        tmpl = D.init_params(dcfg, tcfg, key)
-        try:
-            dparams = load_pytree(tmpl, args.ckpt, f"drafter_{args.arch}")
-            print("loaded drafter checkpoint")
-        except Exception as e:
-            print(f"no checkpoint ({e}); using random drafter")
-            dparams = tmpl
-    eng = Engine(tcfg, dcfg, tparams, dparams,
-                 EngineConfig(K=args.k, max_new_tokens=args.max_new,
-                              drafter_mode=args.mode, max_len=args.max_len,
-                              kv_layout="paged", page_size=args.page_size,
-                              pool_pages=args.pool_pages,
-                              prefix_cache=args.prefix_cache),
-                 args.batch)
+    use_compile_cache()
+    tcfg, _, tparams = init_target(args.arch, reduced=args.reduced)
+    eng = build_engine(
+        tcfg, tparams,
+        EngineConfig(K=args.k, max_new_tokens=args.max_new,
+                     drafter_mode=args.mode, max_len=args.max_len,
+                     kv_layout="paged", page_size=args.page_size,
+                     pool_pages=args.pool_pages,
+                     prefix_cache=args.prefix_cache),
+        args.batch, layers=args.layers, ckpt=args.ckpt)
     aeng = AsyncEngine(eng, eos_id=args.eos_id,
                        max_pending=args.max_pending or None)
 

@@ -41,11 +41,6 @@ from repro.sharding.utils import mesh_scope, spec_for
 from repro.training.trainer import TrainConfig
 
 
-# canonical implementation lives in sharding/utils.py (the serving engine
-# needs it too); re-exported here under its historical launcher name
-mesh_context = mesh_scope
-
-
 def batch_spec(mesh, *trailing):
     axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     return P(axes if axes else None, *trailing)
@@ -153,7 +148,7 @@ def build_train_step(tcfg: ModelConfig, dcfg: DrafterConfig,
         )
         extras = {k: jax.ShapeDtypeStruct(s, d)
                   for k, (s, d) in extras_shapes.items()}
-        with mesh_context(mesh):
+        with mesh_scope(mesh):
             shardings = dict(
                 tparams=_shard_tree(mesh, tparams_sds, param_specs(tparams_sds)),
                 dparams=_shard_tree(mesh, dparams_sds, param_specs(dparams_sds)),
@@ -200,7 +195,7 @@ def build_prefill_step(tcfg: ModelConfig, shape_name: str = "prefill_32k",
         )
         extras = {k: jax.ShapeDtypeStruct(s, d)
                   for k, (s, d) in extras_shapes.items()}
-        with mesh_context(mesh):
+        with mesh_scope(mesh):
             shardings = dict(
                 tparams=_shard_tree(mesh, tparams_sds, param_specs(tparams_sds)),
                 tokens=NamedSharding(mesh, batch_spec(mesh, None)),
@@ -245,7 +240,7 @@ def build_serve_step(tcfg: ModelConfig, dcfg: DrafterConfig,
             lambda k: D.init_params(dcfg, tcfg, k, dtype=jnp.bfloat16),
             jax.random.PRNGKey(0))
         state_sds = eval_shape_tree(make_state)
-        with mesh_context(mesh):
+        with mesh_scope(mesh):
             bsp = batch_spec(mesh)
             state_specs = {
                 "tokens": spec_for((GB, max_len), bsp[0]),

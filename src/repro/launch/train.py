@@ -1,9 +1,8 @@
 """Production training launcher.
 
-On a TPU pod this runs the real distributed P-EAGLE training step (the same
-function the dry-run lowers) under ``make_production_mesh``; on CPU it runs
-the reduced configuration end-to-end so the whole pipeline (data → COD →
-segments → step → checkpoint) is exercised anywhere.
+Trains a P-EAGLE drafter on a frozen target at the published widths, or,
+with ``--reduced``, on the 2-layer CPU-scale variant, so the whole pipeline
+(data → COD → segments → step → checkpoint) runs anywhere.
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b \
         --reduced --epochs 10 --segments 2 --ckpt results/ckpt
@@ -15,17 +14,20 @@ import argparse
 import jax
 
 from repro.checkpoint import save_pytree
-from repro.configs import DrafterConfig, get_config
+from repro.configs import DrafterConfig
 from repro.data import MTPPipeline, markov_corpus, self_generated_corpus
-from repro.models import get_model, make_extras
+from repro.launch.build import init_target, use_compile_cache
+from repro.models import make_extras
 from repro.training import Trainer, TrainConfig
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b")
     ap.add_argument("--reduced", action="store_true",
-                    help="CPU-scale config (default on non-TPU backends)")
+                    help="2-layer CPU-scale config (default: published "
+                         "widths)")
     ap.add_argument("--epochs", type=int, default=10)
     ap.add_argument("--batch", type=int, default=16)
     ap.add_argument("--seq-len", type=int, default=48)
@@ -42,14 +44,9 @@ def main():
     ap.add_argument("--ckpt", default="results/ckpt")
     args = ap.parse_args()
 
-    reduced = args.reduced or jax.default_backend() != "tpu"
-    tcfg = get_config(args.arch)
-    if reduced:
-        tcfg = tcfg.reduced()
-    model = get_model(tcfg)
+    print(f"init target {args.arch} (reduced={args.reduced}) ...")
+    tcfg, model, tparams = init_target(args.arch, reduced=args.reduced)
     key = jax.random.PRNGKey(0)
-    print(f"init target {args.arch} (reduced={reduced}) ...")
-    tparams = model.init(key)
 
     if args.data == "self":
         extras_fn = ((lambda b: make_extras(tcfg, b, "prefill", key))

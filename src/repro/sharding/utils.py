@@ -2,7 +2,7 @@
 
 Models sprinkle ``shard_hint(x, "data", None, "model")`` constraints; on a
 single-device CPU run (tests, benchmarks) there is no mesh and the hint is a
-no-op, while under ``mesh_scope`` (``jax.set_mesh``/``with mesh``) in the
+no-op, while under ``mesh_scope`` (``jax.set_mesh``) in the
 dry-run, launchers, and the model-sharded serving engine it becomes
 ``with_sharding_constraint``. Axes that do not exist in the mesh or do not
 divide the corresponding dimension are dropped from the spec rather than
@@ -10,7 +10,6 @@ erroring, which lets one model definition serve every (arch × mesh).
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Optional, Sequence, Union
 
 import jax
@@ -21,35 +20,11 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 AxisEntry = Union[None, str, Sequence[str]]
 
 
-# Legacy-jax fallback (no set_mesh/use_mesh/get_abstract_mesh, e.g. 0.4.x):
-# mesh_scope pushes the concrete Mesh here; a concrete Mesh exposes the same
-# .empty/.axis_names/.shape surface the abstract mesh does.
-_FALLBACK_MESH: list = []
-
-
 def mesh_scope(mesh):
-    """Enter ``mesh`` so ``shard_hint`` / ``spec_for`` / the rules in
-    sharding/rules.py see it during tracing or eager spec resolution.
-
-    Uses ``jax.set_mesh`` / ``jax.sharding.use_mesh`` when the installed jax
-    has them; on legacy jax (0.4.x) falls back to pushing the concrete Mesh
-    onto ``_FALLBACK_MESH`` and entering ``with mesh:`` (the physical
-    resource env bare-``PartitionSpec`` constraints need there)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)       # context manager in jax >= 0.7
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return _legacy_mesh_scope(mesh)
-
-
-@contextlib.contextmanager
-def _legacy_mesh_scope(mesh):
-    _FALLBACK_MESH.append(mesh)
-    try:
-        with mesh:                      # resource env for bare-P constraints
-            yield mesh
-    finally:
-        _FALLBACK_MESH.pop()
+    """Enter ``mesh`` (``jax.set_mesh``) so ``shard_hint`` / ``spec_for`` /
+    the rules in sharding/rules.py see it during tracing or eager spec
+    resolution."""
+    return jax.set_mesh(mesh)
 
 
 def serving_mesh(n_devices: Optional[int] = None):
@@ -83,11 +58,8 @@ def replicate_tree(tree, mesh):
 
 
 def _current_mesh():
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:
-        mesh = _FALLBACK_MESH[-1] if _FALLBACK_MESH else None
-    if mesh is None or mesh.empty or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or not mesh.axis_names:
         return None
     return mesh
 

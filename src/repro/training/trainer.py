@@ -14,6 +14,7 @@ The AR EAGLE-3 baseline trains through ``losses.ttt_forward_loss``
 """
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass
 from typing import Callable, Optional
@@ -79,7 +80,9 @@ def make_train_step(tcfg: ModelConfig, dcfg: DrafterConfig,
         metrics.update(om)
         return dparams, opt_state, metrics
 
-    return jax.jit(step)
+    # dparams and opt_state are replaced every step: donating them lets the
+    # updated trees reuse their buffers instead of holding both copies live
+    return jax.jit(step, donate_argnums=(1, 2))
 
 
 def make_segment_step(tcfg: ModelConfig, dcfg: DrafterConfig,
@@ -108,7 +111,7 @@ def make_segment_step(tcfg: ModelConfig, dcfg: DrafterConfig,
             loss_fn, has_aux=True)(dparams)
         return grads, metrics
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
     def apply_fn(dparams, opt_state, grads):
         updates, opt_state, om = adamw_update(
             grads, opt_state, dparams, lr=sched,

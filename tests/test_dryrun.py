@@ -26,8 +26,8 @@ from repro.configs.base import InputShape
 import repro.launch.steps as S
 from repro.launch.mesh import make_debug_mesh
 from repro.launch.steps import (build_prefill_step, build_serve_step,
-                                build_train_step, mesh_context,
-                                resolve_drafter)
+                                build_train_step, resolve_drafter)
+from repro.sharding.utils import mesh_scope
 
 S.INPUT_SHAPES = dict(S.INPUT_SHAPES)
 S.INPUT_SHAPES["train_4k"] = InputShape("train_4k", 64, 8, "train")
@@ -49,7 +49,7 @@ av = [args[k] for k in order]
 sv = [sh[k] for k in order]
 if kind == "train":
     av.append(extras); sv.append(exsh)
-with mesh_context(mesh):
+with mesh_scope(mesh):
     comp = jax.jit(fn, in_shardings=tuple(sv)).lower(*av).compile()
 cost = comp.cost_analysis()
 if isinstance(cost, list):        # jax 0.4.x: one dict per device
