@@ -28,6 +28,7 @@ from typing import Iterator, List, Optional
 import numpy as np
 
 from repro.core import cod, partition
+from repro.tracing import span
 
 
 @dataclass
@@ -110,16 +111,18 @@ class MTPPipeline:
         if self.shuffle:
             self.rng.shuffle(idx)
         for s in range(0, len(idx) - self.batch + 1, self.batch):
-            rows = self.corpus[idx[s:s + self.batch]]
-            pos = np.zeros((self.batch, self.M), np.int32)
-            dep = np.zeros((self.batch, self.M), np.int32)
-            lab = np.zeros((self.batch, self.M), np.int32)
-            for b in range(self.batch):
-                pos[b], dep[b], lab[b] = self._expand_row(rows[b])
-            if self.segments <= 1:
-                yield MTPBatch(rows, pos, dep, lab)
-            else:
-                yield self._segment_batch(rows, pos, dep, lab)
+            with span("train.batch"):    # not across the yield
+                rows = self.corpus[idx[s:s + self.batch]]
+                pos = np.zeros((self.batch, self.M), np.int32)
+                dep = np.zeros((self.batch, self.M), np.int32)
+                lab = np.zeros((self.batch, self.M), np.int32)
+                for b in range(self.batch):
+                    pos[b], dep[b], lab[b] = self._expand_row(rows[b])
+                if self.segments <= 1:
+                    out = MTPBatch(rows, pos, dep, lab)
+                else:
+                    out = self._segment_batch(rows, pos, dep, lab)
+            yield out
 
     def _segment_batch(self, rows, pos, dep, lab) -> List[MTPBatch]:
         """Algorithm 1 per row; segments are padded to a common static shape

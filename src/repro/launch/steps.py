@@ -249,6 +249,7 @@ def build_serve_step(tcfg: ModelConfig, dcfg: DrafterConfig,
                 "taps_last": spec_for((GB, 3 * tcfg.d_model), bsp[0], "model"),
                 "tcache": cache_specs(state_sds["tcache"]),
                 "dcache": cache_specs(state_sds["dcache"]),
+                "drafts": spec_for((GB, max_len, ecfg.K), bsp[0]),
                 "new_count": spec_for((GB,), bsp[0]),
                 "slot_iters": spec_for((GB,), bsp[0]),
                 "iters": P(), "row_iters": P(), "committed": P(),

@@ -145,7 +145,8 @@ def write_slot(dst, src, slot: Array, axes):
 
 def reset_slot(tree, slot: Array, axes, fills: Optional[dict] = None):
     """Blank batch row ``slot``: cache ``positions`` leaves become -1 (empty —
-    nothing to attend), every other batched leaf becomes 0. ``fills`` overrides
+    nothing to attend), and so does the ``drafts`` log (no draft proposed);
+    every other batched leaf becomes 0. ``fills`` overrides
     the fill value by leaf name (e.g. {"new_count": max_new} to keep a freed
     slot frozen under the Engine's budget check). Leaves without a batch axis
     are untouched."""
@@ -155,7 +156,7 @@ def reset_slot(tree, slot: Array, axes, fills: Optional[dict] = None):
         if ax < 0:
             return d
         name = _path_str(path).rsplit("/", 1)[-1]
-        fill = fills.get(name, -1 if name == "positions" else 0)
+        fill = fills.get(name, -1 if name in ("positions", "drafts") else 0)
         shape = list(d.shape)
         shape[ax] = 1
         row = jnp.full(shape, fill, d.dtype)

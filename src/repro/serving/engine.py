@@ -55,6 +55,7 @@ from repro.serving.sampling import (SamplingParams, batch_sampling_state,
                                     sampling_state_sds, step_keys)
 from repro.sharding import rules as shard_rules
 from repro.sharding.utils import replicate_tree, serving_mesh
+from repro.tracing import span
 
 Array = jax.Array
 
@@ -219,6 +220,10 @@ def make_decode_state(model, tcfg: ModelConfig, dcfg: Optional[DrafterConfig],
     }
     if ecfg.drafter_mode != "none":
         state["dcache"] = D.make_cache(dcfg, batch, ecfg.max_len, dtype=cdt)
+        # row c: the K drafts proposed from committed position c (the
+        # iteration that verified from last == c); -1 where none was
+        state["drafts"] = jnp.full((batch, ecfg.max_len, ecfg.K), -1,
+                                   jnp.int32)
     return state
 
 
@@ -363,8 +368,16 @@ class Engine:
         categorical draws, the pre-SamplingParams per-step cost);
         ``Engine.step`` picks a twin host-side per call. Both twins emit
         identical tokens for greedy rows, so the choice is purely perf."""
-        return {g: jax.jit(functools.partial(fn, greedy_only=g),
-                           **jit_kwargs) for g in (False, True)}
+        return {g: jax.jit(functools.update_wrapper(
+                    functools.partial(fn, greedy_only=g), fn), **jit_kwargs)
+                for g in (False, True)}
+
+    def _set_table_row_impl(self, block_table, slot, row):
+        """Block-table row ``slot`` := ``row`` (page growth)."""
+        return block_table.at[slot].set(row)
+
+    def _drafts_row_impl(self, drafts, slot):
+        return jax.lax.dynamic_index_in_dim(drafts, slot, keepdims=False)
 
     def _build_jits(self):
         if self.mesh is None:
@@ -388,8 +401,8 @@ class Engine:
             # one trace for every (slot, page-count) combination: slot and
             # the full-width block-table row are both traced, so decode-time
             # growth never recompiles (pinned by tests/test_cache_ops.py)
-            self._set_table_row = jax.jit(
-                lambda bt, slot, row: bt.at[slot].set(row))
+            self._set_table_row = jax.jit(self._set_table_row_impl)
+            self._drafts_row = jax.jit(self._drafts_row_impl)
             if self.paged:
                 # swap-to-host: one gather trace serves every (slot, row)
                 # pair; scatter is the admit trace minus the resume fixup
@@ -460,8 +473,10 @@ class Engine:
             self._swap_scatter = jj(self._swap_scatter_impl,
                                     in_shardings=(psh, rp, rp, rp, rp),
                                     out_shardings=psh)
-        self._set_table_row = jj(lambda bt, slot, row: bt.at[slot].set(row),
+        self._set_table_row = jj(self._set_table_row_impl,
                                  in_shardings=(rp, rp, rp), out_shardings=rp)
+        self._drafts_row = jj(self._drafts_row_impl, in_shardings=(rp, rp),
+                              out_shardings=rp)
 
     # ------------------------------------------------------------------
     # prefill
@@ -930,20 +945,33 @@ class Engine:
         if got is None:
             return state, False
         self._slot_pages[slot].extend(got)
-        # blank-on-alloc: a recycled page may carry the previous owner's
-        # stale positions, and growth splices it into the table without
-        # the full overwrite an admission scatter does — blank BEFORE the
-        # table maps it, so it can never read as attendable history
-        grow = np.full((self.pages_per_slot,), -1, np.int32)
-        grow[:len(got)] = got
-        state = self._blank_row(state, jnp.asarray(grow))
-        row = np.full((self.pages_per_slot,), -1, np.int32)
-        row[:len(self._slot_pages[slot])] = self._slot_pages[slot]
-        state = dict(state)
-        state["block_table"] = self._set_table_row(
-            state["block_table"], jnp.asarray(slot, jnp.int32),
-            jnp.asarray(row))
+        with span("serve.blank"):
+            # blank-on-alloc: a recycled page may carry the previous
+            # owner's stale positions, and growth splices it into the table
+            # without the full overwrite an admission scatter does — blank
+            # BEFORE the table maps it, so it can never read as attendable
+            # history
+            grow = np.full((self.pages_per_slot,), -1, np.int32)
+            grow[:len(got)] = got
+            state = self._blank_row(state, jnp.asarray(grow))
+            row = np.full((self.pages_per_slot,), -1, np.int32)
+            row[:len(self._slot_pages[slot])] = self._slot_pages[slot]
+            state = dict(state)
+            state["block_table"] = self._set_table_row(
+                state["block_table"], jnp.asarray(slot, jnp.int32),
+                jnp.asarray(row))
         return state, True
+
+    def slot_drafts(self, state: dict, slot: int) -> Optional[np.ndarray]:
+        """Host copy of ``slot``'s draft log, (max_len, K) int32: row c
+        holds the K drafts proposed from committed position c, -1 where
+        none was. None when the engine runs no drafter. One slot-row
+        readback; the scheduler makes it once per finished or evicted
+        request, never per iteration."""
+        if "drafts" not in state:
+            return None
+        return np.asarray(self._drafts_row(state["drafts"],
+                                           jnp.asarray(slot, jnp.int32)))
 
     def prefill_into_slot(self, state: dict, prompt, slot: int,
                           extras: Optional[dict] = None,
@@ -1623,13 +1651,15 @@ class Engine:
         tparams, dparams = self._rep(tparams), self._rep(dparams)
         table = state["block_table"]
         core = {k: v for k, v in state.items() if k != "block_table"}
-        view = self._rep(cache_ops.gather_state(core, table, self.pspec))
+        with jax.named_scope("gather"):
+            view = self._rep(cache_ops.gather_state(core, table, self.pspec))
         view = speculative_step(self.model, self.tcfg, self.dcfg, self.ecfg,
                                 tparams, dparams, view,
                                 active_mask=active, max_new=max_new,
                                 k_row=k_row, greedy_only=greedy_only)
-        view = self._rep(view)
-        core = cache_ops.scatter_state(core, view, table, self.pspec)
+        with jax.named_scope("scatter"):
+            view = self._rep(view)
+            core = cache_ops.scatter_state(core, view, table, self.pspec)
         core["block_table"] = table
         return core
 
@@ -1750,100 +1780,113 @@ def speculative_step(model, tcfg: ModelConfig, dcfg: Optional[DrafterConfig],
         policy = (draft_keys(samp, c + 1, K), samp["temperature"],
                   samp["top_k"], samp["top_p"])
 
-    if ecfg.drafter_mode == "parallel":
-        drafts, dlogits, dcache = D.draft_parallel(
-            dcfg, tcfg, dparams, state["dcache"], tok_next,
-            state["taps_last"], c - 1, K, policy=policy)
-    elif ecfg.drafter_mode == "ar":
-        drafts, dlogits, dcache = D.draft_ar(
-            dcfg, tcfg, dparams, state["dcache"], tok_next,
-            state["taps_last"], c - 1, K, policy=policy)
-    else:
-        drafts = jnp.zeros((B, 0), jnp.int32)
-        dlogits, dcache = None, None
+    with jax.named_scope("draft"):
+        if ecfg.drafter_mode == "parallel":
+            drafts, dlogits, dcache = D.draft_parallel(
+                dcfg, tcfg, dparams, state["dcache"], tok_next,
+                state["taps_last"], c - 1, K, policy=policy)
+        elif ecfg.drafter_mode == "ar":
+            drafts, dlogits, dcache = D.draft_ar(
+                dcfg, tcfg, dparams, state["dcache"], tok_next,
+                state["taps_last"], c - 1, K, policy=policy)
+        else:
+            drafts = jnp.zeros((B, 0), jnp.int32)
+            dlogits, dcache = None, None
 
     # target verify over [t_last, d_1..d_K] at positions c..c+K
-    vt = jnp.concatenate([tok_next[:, None], drafts], axis=1)
-    positions = c[:, None] + jnp.arange(K + 1, dtype=jnp.int32)[None]
-    tout = model.forward(tparams, vt, mode="decode",
-                              positions=positions, cache=state["tcache"],
-                              collect_taps=ecfg.drafter_mode != "none")
+    with jax.named_scope("verify"):
+        vt = jnp.concatenate([tok_next[:, None], drafts], axis=1)
+        positions = c[:, None] + jnp.arange(K + 1, dtype=jnp.int32)[None]
+        tout = model.forward(tparams, vt, mode="decode",
+                             positions=positions, cache=state["tcache"],
+                             collect_taps=ecfg.drafter_mode != "none")
 
-    if K == 0:
-        accept_len = jnp.zeros((B,), jnp.int32)
-        if greedy_only:
-            t_star = jnp.argmax(tout.logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("accept"):
+        if K == 0:
+            accept_len = jnp.zeros((B,), jnp.int32)
+            if greedy_only:
+                t_star = jnp.argmax(tout.logits, axis=-1).astype(jnp.int32)
+            else:
+                t_star = SD.sample_token(
+                    step_keys(samp, c + 1), tout.logits[:, 0],
+                    samp["temperature"], samp["top_k"],
+                    samp["top_p"])[:, None]
+        elif greedy_only:
+            accept_len, t_star = SD.greedy_verify(drafts, tout.logits)
+            if k_row is not None:
+                # clip the matched prefix at the row's draft budget — the
+                # correction token t_star[accept_len] is the target argmax
+                # at that position, so the stream content is unchanged
+                accept_len = jnp.minimum(accept_len, k_row)
         else:
-            t_star = SD.sample_token(step_keys(samp, c + 1),
-                                     tout.logits[:, 0], samp["temperature"],
-                                     samp["top_k"], samp["top_p"])[:, None]
-    elif greedy_only:
-        accept_len, t_star = SD.greedy_verify(drafts, tout.logits)
-        if k_row is not None:
-            # clip the matched prefix at the row's draft budget — the
-            # correction token t_star[accept_len] is the target argmax at
-            # that position, so the stream content is unchanged
-            accept_len = jnp.minimum(accept_len, k_row)
-    else:
-        if policy is not None:
-            # sampled rows drew their drafts from the row-warped drafter
-            # distribution — the proposal q MUST be that same distribution
-            # for rejection sampling to stay lossless. Greedy rows keep
-            # the one-hot of their argmax drafts (their sampled-lane
-            # output is discarded by mixed_verify's where-select anyway).
-            q = jnp.where((samp["temperature"] > 0)[:, None, None],
-                          SD.warp_probs(dlogits, samp["temperature"],
-                                        samp["top_k"], samp["top_p"]),
-                          jax.nn.one_hot(drafts, tout.logits.shape[-1],
-                                         dtype=tout.logits.dtype))
-        else:
-            # drafts are the drafter's argmax — a DETERMINISTIC proposal,
-            # so the distribution they were drawn from is a one-hot, and
-            # lossless rejection reduces to accept-with-p(d) / residual
-            # p-masked-at-d (passing the drafter softmax here would
-            # over-accept the drafter's argmax and bias the committed
-            # distribution)
-            q = jax.nn.one_hot(drafts, tout.logits.shape[-1],
-                               dtype=tout.logits.dtype)
-        accept_len, t_star = SD.mixed_verify(
-            step_keys(samp, c + 1), drafts, q, tout.logits,
-            samp["temperature"], samp["top_k"], samp["top_p"], k_row)
+            if policy is not None:
+                # sampled rows drew their drafts from the row-warped
+                # drafter distribution — the proposal q MUST be that same
+                # distribution for rejection sampling to stay lossless.
+                # Greedy rows keep the one-hot of their argmax drafts
+                # (their sampled-lane output is discarded by
+                # mixed_verify's where-select anyway).
+                q = jnp.where((samp["temperature"] > 0)[:, None, None],
+                              SD.warp_probs(dlogits, samp["temperature"],
+                                            samp["top_k"], samp["top_p"]),
+                              jax.nn.one_hot(drafts, tout.logits.shape[-1],
+                                             dtype=tout.logits.dtype))
+            else:
+                # drafts are the drafter's argmax — a DETERMINISTIC
+                # proposal, so the distribution they were drawn from is a
+                # one-hot, and lossless rejection reduces to
+                # accept-with-p(d) / residual p-masked-at-d (passing the
+                # drafter softmax here would over-accept the drafter's
+                # argmax and bias the committed distribution)
+                q = jax.nn.one_hot(drafts, tout.logits.shape[-1],
+                                   dtype=tout.logits.dtype)
+            accept_len, t_star = SD.mixed_verify(
+                step_keys(samp, c + 1), drafts, q, tout.logits,
+                samp["temperature"], samp["top_k"], samp["top_p"], k_row)
 
-    budget = jnp.asarray(ecfg.max_new_tokens, jnp.int32) \
-        if max_new is None else max_new
-    active = state["new_count"] < budget
-    if active_mask is not None:
-        active &= active_mask
-    accept_len = jnp.where(active, accept_len, 0)
+        budget = jnp.asarray(ecfg.max_new_tokens, jnp.int32) \
+            if max_new is None else max_new
+        active = state["new_count"] < budget
+        if active_mask is not None:
+            active &= active_mask
+        accept_len = jnp.where(active, accept_len, 0)
 
-    # commit target cache (invalidate stale attention slots / select
-    # recurrent snapshots at the last accepted token)
-    tcache = cache_ops.commit(tout.cache, tout.aux.get("snapshots"),
-                              c + accept_len, accept_len)
+    with jax.named_scope("commit"):
+        # commit target cache (invalidate stale attention slots / select
+        # recurrent snapshots at the last accepted token)
+        tcache = cache_ops.commit(tout.cache, tout.aux.get("snapshots"),
+                                  c + accept_len, accept_len)
 
-    # append committed tokens t_star[0..accept_len]
-    idx = c[:, None] + 1 + jnp.arange(K + 1, dtype=jnp.int32)[None]
-    keep = jnp.arange(K + 1)[None] <= accept_len[:, None]
-    keep &= active[:, None]
-    safe_idx = jnp.where(keep, idx, state["tokens"].shape[1])
-    tokens = jax.vmap(lambda t, i, v: t.at[i].set(v, mode="drop"))(
-        state["tokens"], safe_idx, t_star)
-    # committed-token logprobs ride the same scatter: tout.logits[:, j] is
-    # the raw target distribution at position c+j, which determined the
-    # token committed at c+1+j — exactly the pairing _token_logprob scores
-    logprobs = jax.vmap(lambda t, i, v: t.at[i].set(v, mode="drop"))(
-        state["logprobs"], safe_idx, _token_logprob(tout.logits, t_star))
+        # append committed tokens t_star[0..accept_len]
+        idx = c[:, None] + 1 + jnp.arange(K + 1, dtype=jnp.int32)[None]
+        keep = jnp.arange(K + 1)[None] <= accept_len[:, None]
+        keep &= active[:, None]
+        safe_idx = jnp.where(keep, idx, state["tokens"].shape[1])
+        tokens = jax.vmap(lambda t, i, v: t.at[i].set(v, mode="drop"))(
+            state["tokens"], safe_idx, t_star)
+        # committed-token logprobs ride the same scatter: tout.logits[:, j]
+        # is the raw target distribution at position c+j, which determined
+        # the token committed at c+1+j — exactly the pairing
+        # _token_logprob scores
+        logprobs = jax.vmap(lambda t, i, v: t.at[i].set(v, mode="drop"))(
+            state["logprobs"], safe_idx, _token_logprob(tout.logits, t_star))
 
-    new_last = jnp.where(active, c + accept_len + 1, c)
-    taps_last = state["taps_last"]
-    if ecfg.drafter_mode != "none":
-        taps_new = jnp.take_along_axis(
-            tout.taps, accept_len[:, None, None], axis=1)[:, 0]
-        taps_last = jnp.where(active[:, None], taps_new, taps_last)
-        # extend drafter cache across the verified block (stale tail is
-        # auto-invalidated by the next positional write)
-        dcache = D.extend(dcfg, tcfg, dparams, dcache, t_star, tout.taps,
-                          positions)
+        new_last = jnp.where(active, c + accept_len + 1, c)
+        taps_last = state["taps_last"]
+        if ecfg.drafter_mode != "none":
+            taps_new = jnp.take_along_axis(
+                tout.taps, accept_len[:, None, None], axis=1)[:, 0]
+            taps_last = jnp.where(active[:, None], taps_new, taps_last)
+            # extend drafter cache across the verified block (stale tail is
+            # auto-invalidated by the next positional write)
+            dcache = D.extend(dcfg, tcfg, dparams, dcache, t_star, tout.taps,
+                              positions)
+            # the drafts land in row c, the position they were proposed
+            # from, the way tokens are written (inactive rows drop)
+            row = jnp.where(active, c, state["drafts"].shape[1])
+            drafts_log = jax.vmap(
+                lambda t, i, v: t.at[i].set(v, mode="drop"))(
+                state["drafts"], row, drafts)
 
     ncommit = jnp.where(active, accept_len + 1, 0)
     new_state = dict(
@@ -1861,5 +1904,5 @@ def speculative_step(model, tcfg: ModelConfig, dcfg: Optional[DrafterConfig],
     )
     if ecfg.drafter_mode != "none":
         new_state["dcache"] = dcache
+        new_state["drafts"] = drafts_log
     return new_state
-

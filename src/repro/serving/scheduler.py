@@ -110,6 +110,7 @@ from repro.models import make_extras
 from repro.serving.engine import Engine
 from repro.serving.sampling import SamplingParams
 from repro.serving.speculation import SpeculationConfig, SpeculationController
+from repro.tracing import GcSpans, span
 
 QUEUED = "queued"
 PREFILLING = "prefilling"
@@ -166,6 +167,10 @@ class Request:                # arrays, and membership tests (abort from
     iters: int = 0                 # decode iterations this request was live
     cached_tokens: int = 0         # prompt positions served from the prefix
     #                                cache across all admissions (0 = cold)
+    # the engine's draft log of this request (Engine.slot_drafts; row c:
+    # the K drafts proposed from stream position c, -1 where none), copied
+    # when it finishes, is evicted or aborted; None without a drafter
+    drafts: Optional[np.ndarray] = None
     # internal bookkeeping
     _prev_new: int = 0             # device-side new_count at last sync
     _prev_last: int = 0            # device-side last position at last sync
@@ -284,6 +289,7 @@ class Scheduler:
         # session state (created by _begin_session; one live session per
         # Scheduler — serve() and a streaming.AsyncEngine each own theirs)
         self._wall_t0: Optional[float] = None
+        self._gc: Optional[GcSpans] = None
 
     # ------------------------------------------------------------------
     # shared loop core — the step/admit/preempt/harvest machinery both
@@ -329,6 +335,11 @@ class Scheduler:
         #                              prefills (net of prefix-cache hits)
         self._next_seq = 0
         self._wall_t0 = None        # None → virtual clock (_advance adds)
+        # each collection of the session on the trace (host.gc) and counted
+        # in the report; the hook is removed by _end_session
+        if self._gc is not None:
+            self._gc.remove()
+        self._gc = GcSpans().install()
         self._t_start = time.perf_counter()
 
     def _advance(self, cost: float) -> None:
@@ -347,6 +358,8 @@ class Scheduler:
         jumped past — is insorted so the trace stays non-decreasing
         (pinned by tests/test_async_serving.py)."""
         t = self._clock if t is None else t
+        with span("serve.request." + kind, rid=rid):
+            pass                    # the request's event on the trace clock
         ev = (t, kind, rid)
         if self._events and t < self._events[-1][0]:
             bisect.insort(self._events, ev, key=lambda e: e[0])
@@ -402,9 +415,22 @@ class Scheduler:
                                req.out_logprobs[req._emitted:]))
             req._emitted = len(req.out_tokens)
 
+    def _keep_drafts(self, req: Request, s: int) -> None:
+        """Merge the draft-log rows slot ``s`` holds for ``req`` (those
+        written since its admission) into ``req.drafts``: one slot-row
+        readback, made when the request leaves its slot."""
+        rows = self.engine.slot_drafts(self._state, s)
+        if rows is None:
+            return
+        if req.drafts is None:
+            req.drafts = np.full_like(rows, -1)
+        mine = (rows >= 0).any(axis=1)
+        req.drafts[mine] = rows[mine]
+
     def _finish_slot(self, s: int) -> None:
         eng = self.engine
         req = self._slot_req[s]
+        self._keep_drafts(req, s)
         req.status = FINISHED
         # wall stamp AFTER device commit: both call sites sit downstream of
         # a blocking host readback of the request's committed tokens (the
@@ -435,6 +461,7 @@ class Scheduler:
             return False
         if req.slot is not None:
             s = req.slot
+            self._keep_drafts(req, s)
             self._active[s] = False
             self._slot_req[s] = None
             self._state = self.engine.free_slot(
@@ -483,6 +510,7 @@ class Scheduler:
         path additionally skips re-paying the prefill FLOPs."""
         eng = self.engine
         req = self._slot_req[s]
+        self._keep_drafts(req, s)
         swapped = False
         if self._swap_beats_recompute(req, s):
             self._state, swapped = eng.swap_out_slot(self._state, s, req.rid)
@@ -643,9 +671,10 @@ class Scheduler:
                                                     seed))
             req.extras = extras
         self._event("admit", req.rid)
-        self._state, first, last = eng.prefill_into_slot(
-            self._state, prompt, s, extras=extras, sampling=req.sampling,
-            max_new=remaining, resume=resume)
+        with span("serve.prefill"):
+            self._state, first, last = eng.prefill_into_slot(
+                self._state, prompt, s, extras=extras,
+                sampling=req.sampling, max_new=remaining, resume=resume)
         if first_admission:
             # wall stamp AFTER the prefill: prefill_into_slot's host
             # readback of the committed position sequences every queued
@@ -689,101 +718,105 @@ class Scheduler:
         recomputed per admission — a slot freed by a preemption (or an
         EOS-at-prefill) is reusable immediately, not after the next sync
         block."""
-        B = self.engine.batch
-        while self._waiting:
-            free = [s for s in range(B) if not self._active[s]
-                    and self._slot_req[s] is None]
-            if not free:
-                break
-            head = self._waiting[0]
-            if not self._head_admissible(head):
-                if self.preempt:
-                    while not self._head_admissible(head):
-                        v = self._lowest_prio_active()
-                        if v is None or (self._prio(self._slot_req[v])
-                                         <= self._prio(head)):
-                            break
-                        self._preempt_slot(v)
-                # swap handles pin resident pages a recompute eviction
-                # would have released — drop lower-priority handles until
-                # the head fits, so swap can only ever ADD admissible
-                # schedules, never wedge one. (Dropping the head's OWN
-                # handle never helps: a swapped resume needs at most the
-                # pages its recompute twin would, so it stays excluded.)
-                while (not self._head_admissible(head)
-                       and self._drop_one_swap(exclude=head)):
-                    pass
+        with span("serve.admit"):
+            B = self.engine.batch
+            while self._waiting:
+                free = [s for s in range(B) if not self._active[s]
+                        and self._slot_req[s] is None]
+                if not free:
+                    break
+                head = self._waiting[0]
                 if not self._head_admissible(head):
-                    break                # head waits for frees (FIFO)
-            self._admit(self._waiting.pop(0), free[0])
+                    if self.preempt:
+                        while not self._head_admissible(head):
+                            v = self._lowest_prio_active()
+                            if v is None or (self._prio(self._slot_req[v])
+                                             <= self._prio(head)):
+                                break
+                            self._preempt_slot(v)
+                    # swap handles pin resident pages a recompute eviction
+                    # would have released — drop lower-priority handles until
+                    # the head fits, so swap can only ever ADD admissible
+                    # schedules, never wedge one. (Dropping the head's OWN
+                    # handle never helps: a swapped resume needs at most the
+                    # pages its recompute twin would, so it stays excluded.)
+                    while (not self._head_admissible(head)
+                           and self._drop_one_swap(exclude=head)):
+                        pass
+                    if not self._head_admissible(head):
+                        break                # head waits for frees (FIFO)
+                self._admit(self._waiting.pop(0), free[0])
 
     def _grow(self) -> np.ndarray:
         """Capacity pass: grow each live slot to cover the coming sync
         block (incremental paged growth); on pool exhaustion preempt the
         lowest-priority slot, or stall when preemption is off. Returns the
         run mask; raises when nothing can step at all."""
-        eng = self.engine
-        B = eng.batch
-        stalled = np.zeros((B,), bool)
-        if eng.incremental:
-            by_prio = sorted(np.flatnonzero(self._active),
-                             key=lambda s: self._prio(self._slot_req[s]))
-            for s in by_prio:
-                if not self._active[s]:      # already evicted this pass
-                    continue
-                req = self._slot_req[s]
-                cap = (req.prompt.size + eng.pos_offset
-                       + req.max_new_tokens + eng.ecfg.K + 1)
-                # a step at position c writes KV c..c+stride-1 and moves
-                # c by at most stride, so sync_every steps need length
-                # last + sync_every*stride, exactly. Under adaptive K the
-                # row's stride is k_row + 1, not the worst-case K + 1 —
-                # a hard row reserves (and can be preempted for) fewer
-                # pages. Writes past the row's allocation are dropped by
-                # scatter and equivalent to commit-invalidated entries,
-                # so the shorter reservation stays bitwise lossless.
-                if self.spec is not None \
-                        and eng.ecfg.drafter_mode != "none":
-                    stride = int(self._k_row[s]) + 1
-                else:
-                    stride = eng.commit_stride
-                target = min(req._prev_last + self.sync_every * stride, cap)
-                self._state, ok = eng.ensure_capacity(self._state, int(s),
-                                                      target)
-                while not ok and self.preempt:
-                    v = self._lowest_prio_active()
-                    self._preempt_slot(v)
-                    if v == s:
-                        break
-                    self._state, ok = eng.ensure_capacity(self._state,
-                                                          int(s), target)
-                while not ok and self._active[s] \
-                        and self._drop_one_swap():
-                    # growth wedged behind handle-pinned pages: fall
-                    # swapped waiters back to recompute and retry
-                    self._state, ok = eng.ensure_capacity(self._state,
-                                                          int(s), target)
-                if not ok and self._active[s]:
-                    stalled[s] = True        # retry once pages free up
-        run = self._active & ~stalled
-        if not run.any():
-            raise RuntimeError(
-                "page pool exhausted and every live slot is stalled; "
-                "enable preemption (Scheduler(preempt=True)) or grow "
-                "pool_pages")
-        return run
+        with span("serve.grow"):
+            eng = self.engine
+            B = eng.batch
+            stalled = np.zeros((B,), bool)
+            if eng.incremental:
+                by_prio = sorted(np.flatnonzero(self._active),
+                                 key=lambda s: self._prio(self._slot_req[s]))
+                for s in by_prio:
+                    if not self._active[s]:      # already evicted this pass
+                        continue
+                    req = self._slot_req[s]
+                    cap = (req.prompt.size + eng.pos_offset
+                           + req.max_new_tokens + eng.ecfg.K + 1)
+                    # a step at position c writes KV c..c+stride-1 and moves
+                    # c by at most stride, so sync_every steps need length
+                    # last + sync_every*stride, exactly. Under adaptive K the
+                    # row's stride is k_row + 1, not the worst-case K + 1 —
+                    # a hard row reserves (and can be preempted for) fewer
+                    # pages. Writes past the row's allocation are dropped by
+                    # scatter and equivalent to commit-invalidated entries,
+                    # so the shorter reservation stays bitwise lossless.
+                    if self.spec is not None \
+                            and eng.ecfg.drafter_mode != "none":
+                        stride = int(self._k_row[s]) + 1
+                    else:
+                        stride = eng.commit_stride
+                    target = min(req._prev_last + self.sync_every * stride,
+                                 cap)
+                    self._state, ok = eng.ensure_capacity(self._state, int(s),
+                                                          target)
+                    while not ok and self.preempt:
+                        v = self._lowest_prio_active()
+                        self._preempt_slot(v)
+                        if v == s:
+                            break
+                        self._state, ok = eng.ensure_capacity(self._state,
+                                                              int(s), target)
+                    while not ok and self._active[s] \
+                            and self._drop_one_swap():
+                        # growth wedged behind handle-pinned pages: fall
+                        # swapped waiters back to recompute and retry
+                        self._state, ok = eng.ensure_capacity(self._state,
+                                                              int(s), target)
+                    if not ok and self._active[s]:
+                        stalled[s] = True        # retry once pages free up
+            run = self._active & ~stalled
+            if not run.any():
+                raise RuntimeError(
+                    "page pool exhausted and every live slot is stalled; "
+                    "enable preemption (Scheduler(preempt=True)) or grow "
+                    "pool_pages")
+            return run
 
     def _dispatch(self, run: np.ndarray) -> None:
         """sync_every speculative iterations over the live slots (jax
         pipelines the dispatches; budget freezes happen on device
         regardless)."""
-        eng = self.engine
-        act_dev, mn_dev = jnp.asarray(run), jnp.asarray(self._max_new)
-        kr_dev = jnp.asarray(self._k_row)
-        for _ in range(self.sync_every):
-            self._state = eng.step(self._state, act_dev, mn_dev, kr_dev)
-            self._n_iters += 1
-            self._advance(self.iter_cost)
+        with span("serve.dispatch"):
+            eng = self.engine
+            act_dev, mn_dev = jnp.asarray(run), jnp.asarray(self._max_new)
+            kr_dev = jnp.asarray(self._k_row)
+            for _ in range(self.sync_every):
+                self._state = eng.step(self._state, act_dev, mn_dev, kr_dev)
+                self._n_iters += 1
+                self._advance(self.iter_cost)
 
     def _harvest(self) -> None:
         """Read back the per-slot counters + newly committed tokens and
@@ -792,43 +825,46 @@ class Scheduler:
         np.asarray readbacks block on every dispatched step, so wall
         stamps taken downstream mark committed work."""
         state = self._state
-        new_count = np.asarray(state["new_count"])
-        slot_iters = np.asarray(state["slot_iters"])
-        last = np.asarray(state["last"])
-        tokens = np.asarray(state["tokens"])
-        logprobs = np.asarray(state["logprobs"])
-        for s in range(self.engine.batch):
-            req = self._slot_req[s]
-            if req is None or not self._active[s]:
-                continue
-            prev_iters, prev_comm = req.iters, req._committed
-            req.iters = req._iters_base + int(slot_iters[s])
-            if new_count[s] > req._prev_new:
-                lo, hi = req._prev_last + 1, last[s] + 1
-                req.out_tokens.extend(tokens[s, lo:hi].tolist())
-                req.out_logprobs.extend(
-                    logprobs[s, lo:hi].astype(float).tolist())
-                req._committed += int(new_count[s]) - req._prev_new
-                req._prev_new = int(new_count[s])
-                req._prev_last = int(last[s])
-            if self.spec is not None:
-                # fold THIS request's decode delta (committed tokens over
-                # engine iterations since the last sync) into its
-                # acceptance EMA and refresh the slot's draft length;
-                # zero-iteration windows (frozen rows) carry no signal
-                d_it = req.iters - prev_iters
-                if d_it > 0:
-                    self.spec.observe(req.rid, req._committed - prev_comm,
-                                      d_it)
-                    self._k_row[s] = self.spec.k_for(req.rid)
-            done = self._clip_and_check_done(req)
-            self._flush(req)
-            if done:
-                self._finish_slot(s)
+        with span("serve.readback"):
+            new_count = np.asarray(state["new_count"])
+            slot_iters = np.asarray(state["slot_iters"])
+            last = np.asarray(state["last"])
+            tokens = np.asarray(state["tokens"])
+            logprobs = np.asarray(state["logprobs"])
+        with span("serve.harvest"):
+            for s in range(self.engine.batch):
+                req = self._slot_req[s]
+                if req is None or not self._active[s]:
+                    continue
+                prev_iters, prev_comm = req.iters, req._committed
+                req.iters = req._iters_base + int(slot_iters[s])
+                if new_count[s] > req._prev_new:
+                    lo, hi = req._prev_last + 1, last[s] + 1
+                    req.out_tokens.extend(tokens[s, lo:hi].tolist())
+                    req.out_logprobs.extend(
+                        logprobs[s, lo:hi].astype(float).tolist())
+                    req._committed += int(new_count[s]) - req._prev_new
+                    req._prev_new = int(new_count[s])
+                    req._prev_last = int(last[s])
+                if self.spec is not None:
+                    # fold THIS request's decode delta (committed tokens over
+                    # engine iterations since the last sync) into its
+                    # acceptance EMA and refresh the slot's draft length;
+                    # zero-iteration windows (frozen rows) carry no signal
+                    d_it = req.iters - prev_iters
+                    if d_it > 0:
+                        self.spec.observe(req.rid, req._committed - prev_comm,
+                                          d_it)
+                        self._k_row[s] = self.spec.k_for(req.rid)
+                done = self._clip_and_check_done(req)
+                self._flush(req)
+                if done:
+                    self._finish_slot(s)
 
     def _end_session(self, wall: float) -> Dict[str, Any]:
         # keep cached pages warm across serves
         self.engine.retain_state(self._state)
+        self._gc.remove()
         return self._report(self._finished, wall, self._n_iters,
                             self._clock, self._events, self._n_preempt)
 
@@ -924,6 +960,8 @@ class Scheduler:
             **({"k_final":
                 self.spec.request_report(r.rid)["k_final"]}
                if self.spec is not None else {}),
+            **({"drafts": self._draft_rows(r)}
+               if self.engine.ecfg.drafter_mode != "none" else {}),
         } for r in sorted(finished, key=lambda r: r.rid)]
         total = sum(r["n_new"] for r in results)
         done = [r for r in results if not r["aborted"]]
@@ -986,7 +1024,20 @@ class Scheduler:
             "p50_wait_vt": float(np.percentile(wait_vt, 50)),
             "p99_wait_vt": float(np.percentile(wait_vt, 99)),
             "events": events,
+            # the session's garbage collections (each a host.gc span on
+            # the trace) and the seconds they took
+            "gc_collections": self._gc.collections,
+            "gc_s": self._gc.seconds,
         }
+
+    def _draft_rows(self, r: Request) -> np.ndarray:
+        """``r``'s draft log over its committed stream: (positions, K),
+        row c the drafts proposed from stream position c, -1 where none
+        was (prompt positions, and positions an accepted draft skipped)."""
+        n = r.prompt.size + self.engine.pos_offset + len(r.out_tokens)
+        if r.drafts is None:
+            return np.full((n, self.engine.ecfg.K), -1, np.int32)
+        return r.drafts[:n].copy()
 
 
 class LLMEngine:

@@ -55,6 +55,7 @@ import numpy as np
 from repro.serving.engine import Engine
 from repro.serving.sampling import SamplingParams
 from repro.serving.scheduler import (ABORTED, FINISHED, Request, Scheduler)
+from repro.tracing import span
 
 
 class StreamHandle:
@@ -372,23 +373,27 @@ class AsyncEngine:
                     self._wake.clear()
                     await self._wake.wait()
                     continue
-                sched._admit_waiting()
-                self._deliver()          # EOS-at-prefill finishes
-                if not sched._active.any():
-                    if sched._waiting:
-                        raise RuntimeError(
-                            "no active slot and the head request cannot "
-                            "be admitted — page pool leak?")
-                    continue
-                run = sched._grow()
-                sched._dispatch(run)     # blocking jax compute
-                sched._harvest()
-                self._deliver()
-                # hand the loop to submitters/consumers between syncs —
-                # this is the only point client coroutines mutate core
-                # state (submit/abort), so the sync above sees a stable
-                # view without locks
-                await asyncio.sleep(0)
+                with span("serve.loop"):
+                    sched._admit_waiting()
+                    with span("serve.deliver"):
+                        self._deliver()  # EOS-at-prefill finishes
+                    if not sched._active.any():
+                        if sched._waiting:
+                            raise RuntimeError(
+                                "no active slot and the head request cannot "
+                                "be admitted — page pool leak?")
+                        continue
+                    run = sched._grow()
+                    sched._dispatch(run)  # blocking jax compute
+                    sched._harvest()
+                    with span("serve.deliver"):
+                        self._deliver()
+                    # hand the loop to submitters/consumers between syncs —
+                    # this is the only point client coroutines mutate core
+                    # state (submit/abort), so the sync above sees a stable
+                    # view without locks
+                    with span("serve.yield"):
+                        await asyncio.sleep(0)
         except BaseException as e:       # noqa: BLE001 — surfaced to clients
             self._fail(e)
         finally:

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import functools
 import time
+import weakref
 from dataclasses import dataclass
 from typing import Callable, Optional
 
@@ -30,6 +31,7 @@ from repro.data.pipeline import MTPBatch, MTPPipeline
 from repro.models import get_model
 from repro.optim import (GradAccumulator, adamw_init, adamw_update,
                          apply_updates, linear_warmup_schedule)
+from repro.tracing import GcSpans, span
 
 
 @dataclass
@@ -51,32 +53,53 @@ def make_train_step(tcfg: ModelConfig, dcfg: DrafterConfig,
 
     def step(tparams, dparams, opt_state, tokens, pos, depth, labels, rng,
              **extras):
-        tout = model.forward(tparams, tokens, mode="train",
-                             collect_taps=True, **extras)
-        taps = jax.lax.stop_gradient(tout.taps)
-        # VLM early fusion: taps cover [vision, text]; drafter positions
-        # index the text region.
-        if tcfg.family == "vlm" and taps.shape[1] != tokens.shape[1]:
-            taps = taps[:, -tokens.shape[1]:]
+        # Each layer is a named sub-program, a nested jit that XLA inlines:
+        # its ops' op_name reads jit(step)/jit(taps|drafter|update)/... The
+        # names are part of the step's lowered structure, which the
+        # persistent compilation cache keys (op_name metadata alone is
+        # not), so a step compiled before they existed is never handed
+        # back in place of this one.
+        @jax.jit
+        def taps(tparams, tokens, extras):
+            tout = model.forward(tparams, tokens, mode="train",
+                                 collect_taps=True, **extras)
+            t = jax.lax.stop_gradient(tout.taps)
+            # VLM early fusion: taps cover [vision, text]; drafter
+            # positions index the text region.
+            if tcfg.family == "vlm" and t.shape[1] != tokens.shape[1]:
+                t = t[:, -tokens.shape[1]:]
+            return t
 
-        def loss_fn(dp):
-            if dcfg.parallel:
-                logits, hidden = D.mtp_forward(dcfg, tcfg, dp, tokens, taps,
-                                               pos, depth, rng=rng)
-                loss, metrics = losses.mtp_loss(
-                    logits, labels, depth,
-                    depth_weight_decay=tc.depth_weight_decay)
-            else:
-                loss, metrics = losses.ttt_forward_loss(
-                    dcfg, tcfg, dp, tokens, taps, hca_weight=tc.hca_weight)
-            return loss, metrics
+        @jax.jit
+        def drafter(dparams, tokens, taps, pos, depth, labels, rng):
+            """The drafter's forward, loss and backward."""
+            def loss_fn(dp):
+                if dcfg.parallel:
+                    logits, hidden = D.mtp_forward(dcfg, tcfg, dp, tokens,
+                                                   taps, pos, depth, rng=rng)
+                    loss, metrics = losses.mtp_loss(
+                        logits, labels, depth,
+                        depth_weight_decay=tc.depth_weight_decay)
+                else:
+                    loss, metrics = losses.ttt_forward_loss(
+                        dcfg, tcfg, dp, tokens, taps,
+                        hca_weight=tc.hca_weight)
+                return loss, metrics
 
-        (loss, metrics), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(dparams)
-        updates, opt_state, om = adamw_update(
-            grads, opt_state, dparams, lr=sched,
-            weight_decay=tc.weight_decay, max_grad_norm=tc.max_grad_norm)
-        dparams = apply_updates(dparams, updates)
+            return jax.value_and_grad(loss_fn, has_aux=True)(dparams)
+
+        @jax.jit
+        def update(grads, opt_state, dparams):
+            updates, opt_state, om = adamw_update(
+                grads, opt_state, dparams, lr=sched,
+                weight_decay=tc.weight_decay,
+                max_grad_norm=tc.max_grad_norm)
+            return apply_updates(dparams, updates), opt_state, om
+
+        (loss, metrics), grads = drafter(
+            dparams, tokens, taps(tparams, tokens, extras), pos, depth,
+            labels, rng)
+        dparams, opt_state, om = update(grads, opt_state, dparams)
         metrics.update(om)
         return dparams, opt_state, metrics
 
@@ -140,6 +163,10 @@ class Trainer:
             tcfg, dcfg, tc)
         self._accum = None
         self.metrics_log = []
+        # each collection while the trainer lives is a host.gc span on the
+        # trace; the hook goes with the trainer
+        self.gc = GcSpans().install()
+        weakref.finalize(self, self.gc.remove)
 
     def _advance_rng(self):
         # training data-order stream: draws are sequential by construction
@@ -149,12 +176,15 @@ class Trainer:
 
     def train_batch(self, batch) -> dict:
         if isinstance(batch, MTPBatch):
-            self.dparams, self.opt_state, m = self._step(
-                self.tparams, self.dparams, self.opt_state,
-                jnp.asarray(batch.tokens), jnp.asarray(batch.pos),
-                jnp.asarray(batch.depth), jnp.asarray(batch.labels),
-                self._advance_rng(), **self.extras)
-            return {k: float(v) for k, v in m.items()}
+            with span("train.put"):
+                arrays = [jnp.asarray(x) for x in (batch.tokens, batch.pos,
+                                                   batch.depth, batch.labels)]
+            with span("train.step"):
+                self.dparams, self.opt_state, m = self._step(
+                    self.tparams, self.dparams, self.opt_state, *arrays,
+                    self._advance_rng(), **self.extras)
+            with span("train.readback"):
+                return {k: float(v) for k, v in m.items()}
         # segmented: within-sequence gradient accumulation (paper §3.2)
         segs = batch
         if self._accum is None:

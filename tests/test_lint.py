@@ -191,6 +191,37 @@ def test_trace01_sees_through_jit_wrapper_helpers():
     assert "TRACE01" not in rules_of(out)
 
 
+def test_trace01_sees_through_update_wrapper():
+    # naming the partial after the wrapped impl (so the compiled module
+    # carries its name) keeps the partial's binding visible
+    out = lint("""
+        import functools
+        import jax
+
+        def _greedy_twins(fn, **kw):
+            return {g: jax.jit(functools.update_wrapper(
+                        functools.partial(fn, greedy_only=g), fn), **kw)
+                    for g in (False, True)}
+
+        def _step_impl(state, greedy_only=False):
+            return state
+
+        step = _greedy_twins(_step_impl)
+    """)
+    assert "TRACE01" not in rules_of(out)
+    unbound = lint("""
+        import functools
+        import jax
+
+        def _step_impl(state, greedy_only=False):
+            return state
+
+        step = jax.jit(functools.update_wrapper(
+            functools.partial(_step_impl), _step_impl))
+    """)
+    assert "TRACE01" in rules_of(unbound)
+
+
 def test_trace02_flags_host_materialization_in_jitted_body():
     out = lint("""
         import jax

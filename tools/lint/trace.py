@@ -33,6 +33,9 @@ from tools.lint.core import Finding, ParsedModule, dotted_name
 
 JIT = "jax.jit"
 PARTIAL = "functools.partial"
+# functools.update_wrapper(partial(...), fn) names a partial after fn (JAX
+# then names the compiled module after it); the partial inside still binds
+UPDATE_WRAPPER = "functools.update_wrapper"
 # helpers that jit their first argument (possibly wrapping it in a partial)
 JIT_WRAPPERS = {"_greedy_twins"}
 # module-level functions that are traced from inside jitted bodies even
@@ -77,6 +80,15 @@ def _static_names(call: ast.Call, fn) -> Set[str]:
     return out
 
 
+def _jit_target(arg: ast.AST, mod: ParsedModule) -> ast.AST:
+    """The callable a jit call compiles, seen through an
+    ``update_wrapper`` naming wrapper (its first argument)."""
+    if isinstance(arg, ast.Call) and arg.args \
+            and mod.resolve(arg.func) == UPDATE_WRAPPER:
+        return arg.args[0]
+    return arg
+
+
 def _local_defs(mod: ParsedModule) -> Dict[str, ast.FunctionDef]:
     return {n.name: n for n in ast.walk(mod.tree)
             if isinstance(n, ast.FunctionDef)}
@@ -103,7 +115,7 @@ def _jitted_defs(mod: ParsedModule) -> Dict[str, ast.Call]:
         is_wrapper = fname.split(".")[-1] in JIT_WRAPPERS
         if not (is_jit or is_wrapper):
             continue
-        arg = node.args[0]
+        arg = _jit_target(node.args[0], mod)
         # unwrap functools.partial(fn, bound=...) around the jitted def
         if isinstance(arg, ast.Call) and mod.resolve(arg.func) == PARTIAL \
                 and arg.args:
@@ -126,7 +138,7 @@ def _partial_bound_names(mod: ParsedModule) -> Set[str]:
         if not (isinstance(node, ast.Call) and node.args
                 and mod.resolve(node.func) == JIT):
             continue
-        arg = node.args[0]
+        arg = _jit_target(node.args[0], mod)
         if isinstance(arg, ast.Call) and mod.resolve(arg.func) == PARTIAL:
             bound.update(kw.arg for kw in arg.keywords if kw.arg)
     return bound
