@@ -13,9 +13,9 @@ models/layers.make_kv_cache.
 layout): K/V live in a shared pool of fixed-size position pages and each
 batch row owns a block table. The page id is scalar-prefetched into the
 BlockSpec index map, so every grid step DMAs one page straight from the
-pool — the gather happens in the index stream, and the contiguous per-slot
-view the CPU path materializes (cache_ops.gather_state) never exists in
-HBM. Unallocated table entries (-1) are masked in-kernel.
+pool — the gather happens in the index stream, and not even the one-layer
+view the engine's jnp path reads (models/layers.paged_view) exists in HBM.
+Unallocated table entries (-1) are masked in-kernel.
 """
 from __future__ import annotations
 

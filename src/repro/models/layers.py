@@ -311,3 +311,81 @@ def cache_update(cache: dict, k_new: Array, v_new: Array,
     k2, v2, p2 = jax.vmap(upd_row)(cache["k"], cache["v"], cache["positions"],
                                    k_new, v_new, slot, abs_pos)
     return {"k": k2, "v": v2, "positions": p2, "ring": cache["ring"]}
+
+
+# ---------------------------------------------------------------------------
+# paged KV caches (serving/cache_ops.py): pools read and written in place
+# ---------------------------------------------------------------------------
+
+POOL_LEAVES = ("k", "v", "positions")
+
+
+def is_paged(cache: Optional[dict]) -> bool:
+    """A paged KV cache: the pools of ``make_kv_cache``'s leaves, k/v
+    ``(NP, page, KV, hd)`` and positions ``(NP, page)``, with the slots'
+    ``block_table`` (B, nb) beside them. A ``layer`` index, where present,
+    says the pools are stacked over layers and this layer is that row."""
+    return isinstance(cache, dict) and "block_table" in cache
+
+
+def _pool_index(cache: dict, *idx):
+    layer = cache.get("layer")
+    return idx if layer is None else (layer,) + idx
+
+
+def paged_view(cache: dict, dtype) -> tuple:
+    """One layer's (k, v, positions) as the contiguous per-slot view,
+    k/v ``(B, nb*page, KV, hd)`` in ``dtype`` and positions ``(B, nb*page)``,
+    read from its pages through the block table (the layer's share of
+    ``cache_ops.gather_pages``). Unmapped entries (-1) read page 0 with
+    their positions forced to -1, so they are empty to every mask."""
+    table = cache["block_table"]
+    B, nb = table.shape
+    page = cache["positions"].shape[-1]
+    idx = _pool_index(cache, jnp.clip(table, 0, None))
+
+    def read(pool):
+        # a page's (page, KV, hd) as (page*KV, hd) rows, the same bytes: a
+        # TPU v5e gathers these twice as fast as pages whose two minor dims
+        # (KV, hd) tile as 2-row tiles
+        rows = pool.reshape(pool.shape[:-3] + (-1, pool.shape[-1]))
+        v = rows[idx]                                # (B, nb, page*KV, hd)
+        return v.reshape((B, nb * page) + pool.shape[-2:]).astype(dtype)
+
+    pos = cache["positions"][idx].reshape(B, nb * page)
+    pos = jnp.where(jnp.repeat(table < 0, page, axis=1), -1, pos)
+    return read(cache["k"]), read(cache["v"]), pos
+
+
+def paged_rows(cache: dict, pos: Array, T: int):
+    """Pool index of rows ``pos[b] .. pos[b]+T-1`` of each slot: (page,
+    offset) arrays (B, T). A row past the slot's mapped pages (table -1,
+    or beyond max_len) gets the out-of-range page ``NP``, so a write with
+    ``mode="drop"`` skips it, as the view's scatter drops it."""
+    table = cache["block_table"]
+    nb = table.shape[1]
+    NP, page = cache["positions"].shape[-2:]
+    rows = pos[:, None] + jnp.arange(T, dtype=pos.dtype)[None, :]
+    blk = rows // page
+    pg = jnp.take_along_axis(table, jnp.clip(blk, 0, nb - 1), axis=1)
+    pg = jnp.where((blk < nb) & (pg >= 0), pg, NP)
+    return _pool_index(cache, pg, rows % page), rows
+
+
+def paged_update(cache: dict, k_new: Array, v_new: Array,
+                 pos: Array) -> dict:
+    """``cache_update`` on a paged cache, in place: the T new rows of each
+    slot (absolute positions ``pos[b] .. pos[b]+T-1``) go into their pages
+    and nothing else is written. Rows at and past ``pos`` hold no valid
+    entry beyond the ones written here (commit invalidates each rejected
+    row, ``cache_ops.commit``), so the stale-history pass of the
+    contiguous path has nothing to clear."""
+    idx, rows = paged_rows(cache, pos, k_new.shape[1])
+    out = dict(cache)
+    out["k"] = cache["k"].at[idx].set(k_new.astype(cache["k"].dtype),
+                                      mode="drop")
+    out["v"] = cache["v"].at[idx].set(v_new.astype(cache["v"].dtype),
+                                      mode="drop")
+    out["positions"] = cache["positions"].at[idx].set(
+        rows.astype(cache["positions"].dtype), mode="drop")
+    return out

@@ -102,23 +102,35 @@ def attn_apply(p: dict, x: Array, *, cfg: ModelConfig, kind: str,
     if mode == "decode":
         assert cache is not None
         pos0 = positions[:, 0]
+        paged = L.is_paged(cache)
+        # a paged cache is read from its pages through the block table:
+        # the same view, one layer at a time
+        if paged:
+            with jax.named_scope("gather"):
+                ck, cv, cpos = L.paged_view(cache, q.dtype)
+        else:
+            ck, cv = cache["k"].astype(q.dtype), cache["v"].astype(q.dtype)
+            cpos = cache["positions"]
         # two-phase: attend [old cache] + [current block], merge by LSE,
         # THEN insert. Avoids copying the cache and — critically for ring
         # (sliding-window) caches — avoids evicting in-window entries the
         # current queries still need to read.
-        old_kpos = jnp.where(cache["positions"] >= pos0[:, None], -1,
-                             cache["positions"])   # mask stale history
+        old_kpos = jnp.where(cpos >= pos0[:, None], -1,
+                             cpos)                 # mask stale history
         mask1 = L.cache_mask_fn(positions, old_kpos, window=window)
         o1, m1, l1 = L.blocked_attention(
-            q, cache["k"].astype(q.dtype), cache["v"].astype(q.dtype),
-            scale=scale, mask_fn=mask1, logit_cap=cfg.logit_softcap,
-            return_stats=True)
+            q, ck, cv, scale=scale, mask_fn=mask1,
+            logit_cap=cfg.logit_softcap, return_stats=True)
         mask2 = L.cache_mask_fn(positions, positions, window=window)
         o2, m2, l2 = L.blocked_attention(
             q, k, v, scale=scale, mask_fn=mask2,
             logit_cap=cfg.logit_softcap, return_stats=True)
         out = L.merge_attention(o1, m1, l1, o2, m2, l2)
-        cache = L.cache_update(cache, k, v, pos0)
+        if paged:
+            with jax.named_scope("scatter"):
+                cache = L.paged_update(cache, k, v, pos0)
+        else:
+            cache = L.cache_update(cache, k, v, pos0)
     else:
         if cache is not None:  # prefill: also populate the cache
             ins = min(T, cache["k"].shape[1])
@@ -321,12 +333,34 @@ def forward(cfg: ModelConfig, params: dict, tokens: Array, *,
             (params["blocks"], dummy))
         new_cache = None
     else:
-        (x, taps, lb, z, base), new_bcache = jax.lax.scan(
-            scan_body,
-            (x, taps0, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32),
-             jnp.zeros((), jnp.int32)),
-            (params["blocks"], bcaches))
-        new_cache = {"blocks": new_bcache}
+        # a paged slot's stacked pools ride the carry, so each layer writes
+        # its new rows into them in place (as scan ys they would be a whole
+        # new pool per layer); its ring flag and block table stay xs
+        pools = {sl: {n: c[n] for n in L.POOL_LEAVES}
+                 for sl, c in bcaches.items() if L.is_paged(c) and n_sb}
+
+        def paged_body(carry, xs):
+            inner, pools = carry
+            bparams, bcache = xs
+            layer = inner[-1] // period              # this block's row
+            bcache = {sl: {**c, **pools[sl], "layer": layer}
+                      if sl in pools else c for sl, c in bcache.items()}
+            inner, ncache = scan_body(inner, (bparams, bcache))
+            pools = {sl: {n: ncache[sl][n] for n in L.POOL_LEAVES}
+                     for sl in pools}
+            ncache = {sl: {n: a for n, a in c.items()
+                           if n not in L.POOL_LEAVES + ("layer",)}
+                      if sl in pools else c for sl, c in ncache.items()}
+            return (inner, pools), ncache
+
+        inner0 = (x, taps0, jnp.zeros((), jnp.float32),
+                  jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32))
+        xs_cache = {sl: {n: a for n, a in c.items() if n not in L.POOL_LEAVES}
+                    if sl in pools else c for sl, c in bcaches.items()}
+        ((x, taps, lb, z, base), pools), new_bcache = jax.lax.scan(
+            paged_body, (inner0, pools), (params["blocks"], xs_cache))
+        new_cache = {"blocks": {sl: {**c, **pools[sl]} if sl in pools else c
+                                for sl, c in new_bcache.items()}}
 
     # tail layers (when n_layers % period != 0)
     if "tail" in params:

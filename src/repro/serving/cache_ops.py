@@ -33,13 +33,17 @@ lengths — not their worst case — allow. Ring (sliding-window) caches and
 recurrent leaves (SSM state, conv windows, RG-LRU h) are already
 memory-bounded per slot and stay in per-slot rows.
 
-The decode step runs unchanged on a *gathered view*: ``gather_state``
-reassembles each slot's pages into the contiguous per-slot layout the model
-forward expects (the CPU twin of the paged Pallas gather in
-kernels/decode_attention.py, which reads pages through the block table
-without materializing the view), and ``scatter_state`` writes the updated
-view back through the table — so speculative rollback-invalidation and
-recurrent snapshot commit work bit-identically across layouts.
+The target's pools are read and written in place by the decode step:
+``attach_table`` puts the block table beside each paged KV dict, the
+model's decode reads each layer's pages through it and writes only the
+step's new rows (``models/layers.paged_view`` / ``paged_update``), and
+``commit`` writes the rejected rows empty. Every other paged leaf (a
+drafter's cache, or every leaf under the sharded engine) runs on a
+*gathered view*: ``gather_state`` reassembles each slot's pages into the
+contiguous per-slot layout its forward expects, and ``scatter_state``
+writes the updated view back through the table. Either way speculative
+rollback-invalidation and recurrent snapshot commit give the same tokens
+across layouts.
 
 Swap-to-host (the SWAPPED lifecycle state)
 ------------------------------------------
@@ -63,6 +67,8 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.models import layers as L
+
 Array = jax.Array
 _SNAP_LEAVES = ("state", "conv", "h")
 NO_BATCH = -1          # batch_axes sentinel: leaf has no batch dimension
@@ -80,17 +86,24 @@ def _path_str(path) -> str:
     return "/".join(parts)
 
 
-def commit(cache, snapshots, commit_pos: Array, accept_idx: Array):
+def commit(cache, snapshots, commit_pos: Array, accept_idx: Array,
+           block: int = 1):
     """cache: model cache pytree; snapshots: matching pytree from
     ModelOutput.aux["snapshots"] (or None for attention-only models);
     commit_pos (B,): last valid absolute position; accept_idx (B,): index of
-    the last committed token within the just-verified block."""
+    the last committed token within the just-verified block; ``block``: the
+    number of rows the step wrote per slot (K+1). A paged KV cache (pools
+    with their block table, ``L.is_paged``) has only its rejected rows,
+    ``commit_pos+1 .. commit_pos - accept_idx + block - 1``, written empty
+    in place."""
     snap_map = {}
     if snapshots is not None:
         flat, _ = jax.tree_util.tree_flatten_with_path(snapshots)
         snap_map = {_path_str(p): l for p, l in flat}
 
     def fix(path, leaf):
+        if L.is_paged(leaf):
+            return _reject_rows(leaf, commit_pos, accept_idx, block)
         ps = _path_str(path)
         name = ps.rsplit("/", 1)[-1]
         if name == "positions":
@@ -108,7 +121,29 @@ def commit(cache, snapshots, commit_pos: Array, accept_idx: Array):
             return jnp.squeeze(sel, axis=t_axis).astype(leaf.dtype)
         return leaf
 
-    return jax.tree_util.tree_map_with_path(fix, cache)
+    return jax.tree_util.tree_map_with_path(fix, cache, is_leaf=L.is_paged)
+
+
+def _reject_rows(cache: dict, commit_pos: Array, accept_idx: Array,
+                 block: int) -> dict:
+    """Positions -1 at the rows a step wrote past ``commit_pos`` (at most
+    ``block - 1`` a slot), in every layer the pools stack."""
+    n = block - 1
+    if n <= 0:
+        return cache
+
+    def one(pos, table):
+        idx, _ = L.paged_rows({"positions": pos, "block_table": table},
+                              commit_pos + 1, n)
+        pg, off = idx
+        rejected = jnp.arange(n)[None, :] < (n - accept_idx)[:, None]
+        pg = jnp.where(rejected, pg, pos.shape[-2])
+        return pos.at[pg, off].set(-1, mode="drop")
+
+    for _ in range(cache["block_table"].ndim - 2):     # stacked layers
+        one = jax.vmap(one)
+    return {**cache, "positions": one(cache["positions"],
+                                      cache["block_table"])}
 
 
 # ---------------------------------------------------------------------------
@@ -450,6 +485,53 @@ def scatter_state(pstate, view_state, table: Array, spec):
     return jax.tree.map(
         lambda pool, view, tag: view if tag == NOT_PAGED
         else scatter_pages(pool, view, table, tag), pstate, view_state, spec)
+
+
+def attach_table(tree, spec, table: Array):
+    """``tree`` with the block table (B, nb) beside the pools of each paged
+    KV dict (``spec`` tags them), broadcast over the pools' leading stack
+    axes as every other leaf of the dict is stacked: the paged cache the
+    model's decode reads and writes in place (``L.is_paged``)."""
+    def walk(node, sp):
+        if isinstance(node, dict):
+            if sp.get("positions") == PAGED_POS:
+                stack = node["positions"].shape[:-2]
+                return {**node, "block_table": jnp.broadcast_to(
+                    table, stack + table.shape)}
+            return {k: walk(v, sp[k]) for k, v in node.items()}
+        if isinstance(node, (list, tuple)):
+            return type(node)(walk(v, s) for v, s in zip(node, sp))
+        return node
+    return walk(tree, spec)
+
+
+def detach_table(tree):
+    """Inverse of ``attach_table``: drop the block table from each paged
+    KV dict."""
+    return jax.tree.map(
+        lambda d: {k: v for k, v in d.items() if k != "block_table"}
+        if L.is_paged(d) else d, tree, is_leaf=L.is_paged)
+
+
+def take_pools(tree, spec):
+    """``(pools, rest)``: the leaves ``spec`` tags paged, as a tuple in
+    flatten order, and ``tree`` with None in their place. The decode step
+    takes the pools as their own argument, so that they alone are
+    donated."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    tags = jax.tree_util.tree_leaves(spec)
+    pools = tuple(x for x, t in zip(leaves, tags) if t != NOT_PAGED)
+    rest = treedef.unflatten([None if t != NOT_PAGED else x
+                              for x, t in zip(leaves, tags)])
+    return pools, rest
+
+
+def put_pools(rest, pools):
+    """Inverse of ``take_pools``."""
+    leaves, treedef = jax.tree_util.tree_flatten(
+        rest, is_leaf=lambda x: x is None)
+    it = iter(pools)
+    return treedef.unflatten([next(it) if x is None else x for x in leaves])
 
 
 def blank_pages(pstate, table_row: Array, spec):

@@ -386,7 +386,8 @@ class Engine:
             self._prefill_pad = jax.jit(self._prefill_pad_impl)
             self._chunk = jax.jit(self._chunk_impl)
             self._sched_step = self._greedy_twins(self._sched_step_impl)
-            self._paged_step = self._greedy_twins(self._paged_step_impl)
+            self._paged_step = self._greedy_twins(self._paged_step_impl,
+                                                  donate_argnums=2)
             self._admit = jax.jit(self._admit_impl)
             self._paged_admit = jax.jit(self._paged_admit_impl)
             self._free = jax.jit(self._free_impl)
@@ -441,8 +442,9 @@ class Engine:
             # admission/free/growth are then sharded-local data movement
             psh = self.paged_state_shardings
             self._paged_step = self._greedy_twins(
-                self._paged_step_impl, in_shardings=(tp, dp, psh, rp, rp, rp),
-                out_shardings=psh)
+                self._paged_step_impl,
+                in_shardings=(tp, dp, (), psh, rp, rp, rp),
+                out_shardings=((), psh))
             self._paged_admit = jj(self._paged_admit_impl,
                                    in_shardings=(psh, csh, rp, rp, rp, rp,
                                                  rp),
@@ -746,6 +748,32 @@ class Engine:
         return self._pspec
 
     @property
+    def in_place_spec(self):
+        """Tags of the target cache's leaves that the paged step reads and
+        writes in place (its pools); every tag NOT_PAGED where there are
+        none. The model's decode reads these through the block table, so
+        no per-slot view of them is built. The sharded engine gathers
+        every paged leaf, so that its step computes with single-device
+        shapes."""
+        tspec = self.pspec["tcache"]
+        if self.mesh is not None:
+            return jax.tree.map(lambda _: cache_ops.NOT_PAGED, tspec)
+        return tspec
+
+    @property
+    def paged_leaves(self) -> Dict[str, int]:
+        """How many paged leaves the decode step reads in place and how
+        many it still gathers into the per-slot view (0 and 0 off the paged
+        layout)."""
+        if not self.paged:
+            return {"in_place": 0, "gathered": 0}
+        tags = jax.tree.leaves(self.pspec)
+        n = sum(t != cache_ops.NOT_PAGED for t in tags)
+        k = sum(t != cache_ops.NOT_PAGED
+                for t in jax.tree.leaves(self.in_place_spec))
+        return {"in_place": k, "gathered": n - k}
+
+    @property
     def paged_axes(self):
         """batch_axes of the *paged* state: pool leaves have no batch axis,
         so write_slot/reset_slot skip them automatically and only touch
@@ -821,7 +849,10 @@ class Engine:
         pool arrays (the host-side index only maps page ids), so starting
         from a fresh blank pool would orphan every index entry onto zeroed
         pages. The retained state has every slot freed (block-table rows
-        -1, counters inert); only held pages carry meaningful bytes."""
+        -1, counters inert); only held pages carry meaningful bytes. The
+        session's first paged step consumes its target pools (they are
+        donated), so only the state that session retains at its end can
+        start the next one."""
         if self.prefix_cache is None or self._serve_state is None:
             return self.blank_state()
         return self._serve_state
@@ -1581,7 +1612,9 @@ class Engine:
         """One jitted speculative iteration. Without arguments this is the
         legacy whole-batch step; the scheduler passes ``active`` (B,) bool and
         per-slot ``max_new`` (B,) int32. The paged layout always routes
-        through the gather→step→scatter wrapper. Host-side, the engine picks
+        through ``_paged_step_impl``, which takes the target's pools as a
+        donated argument of their own: the state passed in gives up those
+        buffers to the state returned. Host-side, the engine picks
         the mixed-policy or greedy-only trace of the step (``_mixed_policy``;
         output-identical, the greedy twin just skips the sampled lane's
         warps and draws).
@@ -1606,10 +1639,13 @@ class Engine:
                 max_new = jnp.full((B,), self.ecfg.max_new_tokens, jnp.int32)
             if k_row is None:
                 k_row = jnp.full((B,), self.ecfg.K, jnp.int32)
-            return self._paged_step[g](self.tparams, self.dparams, state,
-                                       jnp.asarray(active),
-                                       jnp.asarray(max_new, jnp.int32),
-                                       jnp.asarray(k_row, jnp.int32))
+            pools, rest = self.split_pools(state)
+            pools, rest = self._paged_step[g](
+                self.tparams, self.dparams, pools, rest, jnp.asarray(active),
+                jnp.asarray(max_new, jnp.int32),
+                jnp.asarray(k_row, jnp.int32))
+            rest["tcache"] = cache_ops.put_pools(rest["tcache"], pools)
+            return rest
         if active is None and max_new is None and k_row is None:
             return self._step[g](self.tparams, self.dparams, state)
         if active is None:
@@ -1632,36 +1668,57 @@ class Engine:
                                k_row=k_row, greedy_only=greedy_only)
         return self._rep(out)
 
-    def _paged_step_impl(self, tparams, dparams, state, active, max_new,
-                         k_row, greedy_only=False):
-        """Paged twin of _sched_step_impl: reassemble each slot's pages into
-        the contiguous per-slot view the step consumes (cache_ops.gather),
-        run the identical speculative iteration, scatter the updated view
-        back through the block table. All inside one jit, so rollback
-        invalidation and snapshot commit are bit-identical across layouts —
-        the cross-layout equivalence tests pin this.
+    def split_pools(self, state: dict):
+        """``(pools, rest)`` of a paged state: the target cache's leaves the
+        step updates in place (``in_place_spec``), and the state without
+        them (``cache_ops.take_pools``)."""
+        pools, tcache = cache_ops.take_pools(state["tcache"],
+                                             self.in_place_spec)
+        return pools, {**state, "tcache": tcache}
 
-        Under shard_model the gathered view (and the weights) cross the
-        replication boundary before the step — the all-gather of each
-        slot's pages — and the stepped view is pinned replicated again
-        before ``scatter_state`` writes it back into the sharded pools, so
-        the speculative iteration itself computes with single-device
-        shapes (the losslessness invariant) while pools stay sharded at
-        rest across the host round-trip."""
+    def _paged_step_impl(self, tparams, dparams, pools, state, active,
+                         max_new, k_row, greedy_only=False):
+        """Paged twin of _sched_step_impl. ``pools`` are the target cache's
+        pools (``split_pools``), donated: the model's decode reads each
+        layer's pages through the block table and writes only the new rows
+        into them, and commit writes its rejected rows empty, all in the
+        caller's buffers (``L.paged_view`` / ``L.paged_update``,
+        ``cache_ops.commit``). Every other paged leaf (a drafter's cache)
+        is reassembled into the contiguous per-slot view the step consumes
+        (cache_ops.gather) and scattered back through the block table
+        after it. Both layouts give the same tokens — the cross-layout
+        equivalence tests pin this.
+
+        Under shard_model no pool is read in place: the gathered view (and
+        the weights) cross the replication boundary before the step — the
+        all-gather of each slot's pages — and the stepped view is pinned
+        replicated again before ``scatter_state`` writes it back into the
+        sharded pools, so the speculative iteration itself computes with
+        single-device shapes (the losslessness invariant) while pools stay
+        sharded at rest across the host round-trip."""
         tparams, dparams = self._rep(tparams), self._rep(dparams)
         table = state["block_table"]
         core = {k: v for k, v in state.items() if k != "block_table"}
+        core["tcache"] = cache_ops.put_pools(core["tcache"], pools)
+        ispec = self.in_place_spec
+        gspec = {**self.pspec, "tcache": jax.tree.map(
+            lambda t, i: cache_ops.NOT_PAGED if i else t,
+            self.pspec["tcache"], ispec)}
         with jax.named_scope("gather"):
-            view = self._rep(cache_ops.gather_state(core, table, self.pspec))
+            view = self._rep(cache_ops.gather_state(core, table, gspec))
+            view["tcache"] = cache_ops.attach_table(view["tcache"], ispec,
+                                                    table)
         view = speculative_step(self.model, self.tcfg, self.dcfg, self.ecfg,
                                 tparams, dparams, view,
                                 active_mask=active, max_new=max_new,
                                 k_row=k_row, greedy_only=greedy_only)
         with jax.named_scope("scatter"):
             view = self._rep(view)
-            core = cache_ops.scatter_state(core, view, table, self.pspec)
+            view["tcache"] = cache_ops.detach_table(view["tcache"])
+            core = cache_ops.scatter_state(core, view, table, gspec)
+        pools, core["tcache"] = cache_ops.take_pools(core["tcache"], ispec)
         core["block_table"] = table
-        return core
+        return pools, core
 
     # ------------------------------------------------------------------
     # loops & metrics
@@ -1855,7 +1912,7 @@ def speculative_step(model, tcfg: ModelConfig, dcfg: Optional[DrafterConfig],
         # commit target cache (invalidate stale attention slots / select
         # recurrent snapshots at the last accepted token)
         tcache = cache_ops.commit(tout.cache, tout.aux.get("snapshots"),
-                                  c + accept_len, accept_len)
+                                  c + accept_len, accept_len, block=K + 1)
 
         # append committed tokens t_star[0..accept_len]
         idx = c[:, None] + 1 + jnp.arange(K + 1, dtype=jnp.int32)[None]

@@ -340,6 +340,9 @@ class Scheduler:
         if self._gc is not None:
             self._gc.remove()
         self._gc = GcSpans().install()
+        # how the decode step reaches the paged leaves, on the trace clock
+        with span("serve.paged_leaves", **eng.paged_leaves):
+            pass
         self._t_start = time.perf_counter()
 
     def _advance(self, cost: float) -> None:
@@ -1028,6 +1031,11 @@ class Scheduler:
             # the trace) and the seconds they took
             "gc_collections": self._gc.collections,
             "gc_s": self._gc.seconds,
+            # paged leaves the decode step reads and writes in place, and
+            # those it still gathers into the per-slot view (0 and 0 off
+            # the paged layout; Engine.paged_leaves)
+            "paged_in_place": self.engine.paged_leaves["in_place"],
+            "paged_gathered": self.engine.paged_leaves["gathered"],
         }
 
     def _draft_rows(self, r: Request) -> np.ndarray:

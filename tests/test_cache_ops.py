@@ -20,6 +20,7 @@ from hypothesis import given, settings, strategies as st
 from repro.configs import DrafterConfig, get_config
 from repro.core import drafter as D
 from repro.models import get_model, make_extras
+from repro.models import layers as L
 from repro.serving import Engine, EngineConfig, Request, Scheduler, cache_ops
 
 KEY = jax.random.PRNGKey(3)
@@ -186,6 +187,69 @@ def eng_pool_restored(eng) -> bool:
 # ---------------------------------------------------------------------------
 # BlockAllocator unit tests
 # ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("block", [1, 3])
+def test_paged_step_rows_match_the_view_path(block):
+    """A decode step on the pools in place reads each layer's view as the
+    view path does (``L.paged_view`` against ``cache_ops.gather_pages``),
+    and its writes — each layer's new rows (``L.paged_update``), then the
+    rejected ones written empty (``cache_ops.commit`` on a paged dict) —
+    leave K, V and positions bitwise where the view path leaves them:
+    gather each slot's pages, ``cache_update``, commit, scatter back. Slot
+    2 runs past its mapped pages, whose rows both paths drop; ``block`` 1
+    is a step with no drafter, which rejects nothing."""
+    rng = np.random.default_rng(5)
+    S, B, nb, page, KV, hd = 2, 3, 4, 4, 2, 8       # S layers stacked
+    NP = B * nb + 1
+    table = rng.permutation(NP)[:B * nb].reshape(B, nb)
+    table[2, 3] = -1
+    pos0 = np.array([5, 9, 14], np.int32)           # each slot's length
+    k = rng.normal(size=(S, NP, page, KV, hd)).astype(np.float32)
+    v = rng.normal(size=(S, NP, page, KV, hd)).astype(np.float32)
+    pos = np.full((S, NP, page), -1, np.int32)
+    for b in range(B):
+        for r in range(pos0[b]):
+            if table[b, r // page] >= 0:
+                pos[:, table[b, r // page], r % page] = r
+    k_new = rng.normal(size=(S, B, block, KV, hd)).astype(np.float32)
+    v_new = rng.normal(size=(S, B, block, KV, hd)).astype(np.float32)
+    accept = np.array([0, block - 1, block // 2], np.int32)
+    commit_pos = jnp.asarray(pos0 + accept)
+    tags = {"k": cache_ops.PAGED_KV, "v": cache_ops.PAGED_KV,
+            "positions": cache_ops.PAGED_POS}
+    tb = jnp.asarray(table)
+
+    pools = {"k": jnp.asarray(k), "v": jnp.asarray(v),
+             "positions": jnp.asarray(pos)}
+    view = {n: cache_ops.gather_pages(a, tb, tags[n])
+            for n, a in pools.items()}
+    view["ring"] = jnp.zeros((S,), bool)
+    view = jax.vmap(L.cache_update, in_axes=(0, 0, 0, None))(
+        view, jnp.asarray(k_new), jnp.asarray(v_new), jnp.asarray(pos0))
+    view = cache_ops.commit({"blocks": view}, None, commit_pos,
+                            jnp.asarray(accept))["blocks"]
+    want = {n: cache_ops.scatter_pages(a, view[n], tb, tags[n])
+            for n, a in pools.items()}
+
+    paged = {**pools, "ring": jnp.zeros((S,), bool), "block_table": tb}
+    for s in range(S):
+        got_view = L.paged_view({**paged, "layer": s}, jnp.float32)
+        for n, a in zip(("k", "v", "positions"), got_view):
+            np.testing.assert_array_equal(
+                np.asarray(a), np.asarray(cache_ops.gather_pages(
+                    pools[n][s], tb, tags[n])), err_msg=f"{n} view")
+        paged = L.paged_update({**paged, "layer": s}, jnp.asarray(k_new[s]),
+                               jnp.asarray(v_new[s]), jnp.asarray(pos0))
+    paged = {n: a for n, a in paged.items() if n != "layer"}
+    paged["block_table"] = jnp.broadcast_to(tb, (S,) + tb.shape)
+    got = cache_ops.commit({"blocks": paged}, None, commit_pos,
+                           jnp.asarray(accept), block=block)["blocks"]
+    for n in tags:
+        np.testing.assert_array_equal(np.asarray(got[n]),
+                                      np.asarray(want[n]), err_msg=n)
+    assert int((np.asarray(got["positions"]) >= 0).sum()) > int(
+        (pos >= 0).sum())                  # rows were written, not dropped
+
 
 def test_allocator_alloc_free_cycle():
     a = cache_ops.BlockAllocator(8)

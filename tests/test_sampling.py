@@ -195,13 +195,19 @@ def _setup():
 
 
 @lru_cache(maxsize=None)
-def get_engine(kv_layout="contiguous", batch=2, shard=0, bucket=True):
+def get_engine(kv_layout="contiguous", batch=2, shard=0, bucket=True,
+               mode="parallel", prefix_cache=False, swap=False):
     tcfg, dcfg, tparams, dparams = _setup()
     return Engine(tcfg, dcfg, tparams, dparams,
-                  EngineConfig(K=2, max_new_tokens=8,
-                               drafter_mode="parallel", max_len=64,
+                  EngineConfig(K=2 if mode != "none" else 0,
+                               max_new_tokens=8,
+                               drafter_mode=mode, max_len=64,
                                kv_layout=kv_layout, page_size=8,
-                               bucket_prefill=bucket, shard_model=shard > 0,
+                               bucket_prefill=bucket,
+                               prefix_cache=prefix_cache,
+                               swap="host" if swap else "none",
+                               pool_pages=5 if swap else 0,
+                               shard_model=shard > 0,
                                mesh=serving_mesh(shard) if shard else None),
                   batch)
 
@@ -238,29 +244,55 @@ def test_same_seed_same_tokens_regardless_of_batch_composition():
             err_msg="seeded stream changed with batch composition")
 
 
-@pytest.mark.parametrize("shard", [0, 4, 8])
-def test_mixed_policy_cross_layout_losslessness(shard):
+@pytest.mark.parametrize("shard,case", [
+    pytest.param(0, "parallel", id="0"),
+    pytest.param(4, "parallel", id="4"),
+    pytest.param(8, "parallel", id="8"),
+    pytest.param(0, "none", id="0-none"),
+    pytest.param(0, "ar", id="0-ar"),
+    pytest.param(0, "prefix", id="0-prefix"),
+    pytest.param(0, "swap", id="0-swap"),
+])
+def test_mixed_policy_cross_layout_losslessness(shard, case):
     """A batch mixing greedy and seeded sampled requests: paged + bucketed
     (and optionally model-sharded over ``shard`` forced host devices)
     equals the contiguous exact-length single-device engine bitwise — for
-    BOTH policies. One jitted step per layout serves the whole mix."""
+    BOTH policies. One jitted step per layout serves the whole mix. The
+    single-device paged step reads the target's pools in place; its cases
+    run with no drafter, the "ar" drafter, prefix-cache hits (a second
+    serve on warm pages) and a swap-out/swap-in after a preemption."""
     if shard:
         require_devices(shard)
+    mode = case if case in ("none", "ar") else "parallel"
     prompts = _prompts(5, seed=7, lo=3, hi=10)
+    budgets = [6] * 5
+    if case == "prefix":
+        pre = _prompts(1, seed=8, lo=19, hi=20)[0]
+        prompts = [np.concatenate([pre, p]) for p in prompts]
+    if case == "swap":
+        budgets = [14, 14, 8, 6, 6]
     sps = [SamplingParams.greedy(),
            SamplingParams(temperature=0.7, seed=1),
            SamplingParams(temperature=1.0, top_p=0.9, seed=2),
            None,                                  # engine default (greedy)
            SamplingParams(temperature=0.5, top_k=25, seed=3)]
-    reqs = lambda: [Request(p, max_new_tokens=6, sampling=sp)   # noqa: E731
-                    for p, sp in zip(prompts, sps)]
-    ref = Scheduler(get_engine(bucket=False)).serve(reqs())
-    eng = get_engine("paged", shard=shard)
-    got = Scheduler(eng).serve(reqs())
-    for r, g in zip(ref["results"], got["results"]):
-        np.testing.assert_array_equal(
-            r["tokens"], g["tokens"],
-            err_msg=f"rid {r['rid']} diverged across layouts (shard={shard})")
+    reqs = lambda: [Request(p, max_new_tokens=b, sampling=sp)  # noqa: E731
+                    for p, b, sp in zip(prompts, budgets, sps)]
+    ref = Scheduler(get_engine(bucket=False, mode=mode)).serve(reqs())
+    eng = get_engine("paged", shard=shard, mode=mode,
+                     prefix_cache=case == "prefix", swap=case == "swap")
+    for _ in range(2 if case == "prefix" else 1):
+        got = Scheduler(eng).serve(reqs())
+        for r, g in zip(ref["results"], got["results"]):
+            np.testing.assert_array_equal(
+                r["tokens"], g["tokens"],
+                err_msg=f"rid {r['rid']} diverged across layouts "
+                        f"(shard={shard}, {case})")
+    if case == "prefix":
+        assert got["cache_hit_tokens"] > 0, "the workload was meant to hit"
+        eng.prefix_cache.flush(eng.allocator)
+    if case == "swap":
+        assert got["preempt_swap"] >= 1, "the workload was meant to swap"
     assert eng.allocator.n_free == eng.pool_pages
 
 

@@ -102,15 +102,47 @@ def test_engine_losslessness_greedy(mode):
     assert (np.asarray(spec["state"]["new_count"]) >= max_new).all()
 
 
+def cross_layout_workload(case, vocab):
+    """(prompts, budgets) for a cross-layout case: prompt lengths that hit
+    the pad path, the chunk path and partial pages; for "prefix" a shared
+    preamble longer than two pages; for "swap" the tight-pool mix of
+    tests/test_swap.py, which forces a swap-out and a swap-in."""
+    rng = np.random.default_rng(23)
+    if case == "prefix":
+        pre = rng.integers(1, 200, size=19).astype(np.int32)
+        return [np.concatenate([pre, rng.integers(1, 200, size=n).astype(
+            np.int32)]) for n in (3, 5, 7, 4)], [6, 3, 5, 6]
+    if case == "swap":
+        return [rng.integers(1, 200, size=6).astype(np.int32)
+                for _ in range(3)], [14, 14, 8]
+    lengths = [4, 5, 7, 3, 9]            # pow2, pow2±1, multi-chunk
+    return [rng.integers(1, vocab - 2, size=n).astype(np.int32)
+            for n in lengths], [6, 3, 5, 4, 6]
+
+
 @pytest.mark.parametrize("shard", [0, 4, 8])
-@pytest.mark.parametrize("arch", ["qwen2-1.5b", "mamba2-780m",
-                                  "recurrentgemma-2b"])
-def test_cross_layout_losslessness(arch, shard):
+@pytest.mark.parametrize("arch,case", [
+    pytest.param("qwen2-1.5b", "parallel", id="qwen2-1.5b"),
+    pytest.param("mamba2-780m", "parallel", id="mamba2-780m"),
+    pytest.param("recurrentgemma-2b", "parallel", id="recurrentgemma-2b"),
+    pytest.param("qwen2-1.5b", "none", id="qwen2-1.5b-none"),
+    pytest.param("qwen2-1.5b", "ar", id="qwen2-1.5b-ar"),
+    pytest.param("qwen2-1.5b", "prefix", id="qwen2-1.5b-prefix"),
+    pytest.param("qwen2-1.5b", "swap", id="qwen2-1.5b-swap"),
+])
+def test_cross_layout_losslessness(arch, case, shard):
     """Greedy decode through the paged engine (page-pool KV, block tables,
     power-of-two-bucketed admission prefills) equals the contiguous engine
     with exact-length prefills token-for-token, across prompt lengths that
     hit the pad path, the chunk path, and partial pages — for a dense, an
     SSM, and a hybrid (RG-LRU + local attention) target.
+
+    On one device the paged step reads the target's pools in place and
+    writes only each step's new rows (its rejected rows written empty at
+    commit), so the dense cases also cover: no drafter, the "ar" drafter,
+    a prefix-cache hit (a second serve admits against the first's cached
+    pages, which the step must leave as they were), and swap-out/swap-in
+    after a preemption.
 
     ``shard`` > 0 runs the engine under test model-sharded over that many
     forced host devices (weights + KV pools storage-sharded, both layouts)
@@ -122,31 +154,42 @@ def test_cross_layout_losslessness(arch, shard):
     tparams = m.init(KEY)
     dcfg = DrafterConfig(n_layers=1, k_infer=2).resolve(tcfg)
     dparams = D.init_params(dcfg, tcfg, jax.random.fold_in(KEY, 3))
+    mode = case if case in ("none", "ar") else "parallel"
 
     def make(layout, bucket, sharded=False):
+        paged = layout == "paged"
         return Engine(tcfg, dcfg, tparams, dparams,
-                      EngineConfig(K=2, max_new_tokens=6,
-                                   drafter_mode="parallel", max_len=64,
+                      EngineConfig(K=2 if mode != "none" else 0,
+                                   max_new_tokens=6,
+                                   drafter_mode=mode, max_len=64,
                                    kv_layout=layout, page_size=8,
                                    bucket_prefill=bucket,
+                                   prefix_cache=paged and case == "prefix",
+                                   swap="host" if paged and case == "swap"
+                                   else "none",
+                                   pool_pages=5 if paged and case == "swap"
+                                   else 0,
                                    shard_model=sharded and mesh is not None,
                                    mesh=mesh if sharded else None), 2)
 
-    rng = np.random.default_rng(23)
-    lengths = [4, 5, 7, 3, 9]            # pow2, pow2±1, multi-chunk
-    prompts = [rng.integers(1, tcfg.vocab_size - 2,
-                            size=n).astype(np.int32) for n in lengths]
-    budgets = [6, 3, 5, 4, 6]
+    prompts, budgets = cross_layout_workload(case, tcfg.vocab_size)
     reqs = lambda: [Request(p, max_new_tokens=b)          # noqa: E731
                     for p, b in zip(prompts, budgets)]
     ref = Scheduler(make("contiguous", False)).serve(reqs())
     paged_eng = make("paged", True, sharded=True)
-    got = Scheduler(paged_eng).serve(reqs())
-    for r, g in zip(ref["results"], got["results"]):
-        np.testing.assert_array_equal(
-            r["tokens"], g["tokens"],
-            err_msg=f"{arch}: request {r['rid']} diverged across layouts"
-                    f" (shard={shard})")
+    passes = 2 if case == "prefix" else 1
+    for _ in range(passes):
+        got = Scheduler(paged_eng).serve(reqs())
+        for r, g in zip(ref["results"], got["results"]):
+            np.testing.assert_array_equal(
+                r["tokens"], g["tokens"],
+                err_msg=f"{arch}/{case}: request {r['rid']} diverged across "
+                        f"layouts (shard={shard})")
+    if case == "prefix":
+        assert got["cache_hit_tokens"] > 0, "the workload was meant to hit"
+        paged_eng.prefix_cache.flush(paged_eng.allocator)
+    if case == "swap":
+        assert got["preempt_swap"] >= 1, "the workload was meant to swap"
     # paged bookkeeping drained cleanly
     assert paged_eng.allocator.n_free == paged_eng.pool_pages
     if shard:
@@ -160,6 +203,34 @@ def test_cross_layout_losslessness(arch, shard):
             np.testing.assert_array_equal(
                 r["tokens"], g["tokens"],
                 err_msg=f"{arch}: contiguous sharded diverged (shard={shard})")
+
+
+@pytest.mark.parametrize("arch,counts", [("qwen2-1.5b", (3, 0)),
+                                         ("mamba2-780m", (0, 0))])
+def test_paged_step_leaf_counts(arch, counts):
+    """With no drafter the paged step reads every target pool in place and
+    gathers nothing: qwen2's stacked k, v and positions; mamba2 has no
+    paged leaf. A drafter's cache is still gathered, and so is every pool
+    under the sharded engine. The scheduler reports the counts."""
+    tcfg = get_config(arch).reduced()
+    tparams = get_model(tcfg).init(KEY)
+    eng = Engine(tcfg, None, tparams, None,
+                 EngineConfig(K=0, max_new_tokens=2, drafter_mode="none",
+                              max_len=32, kv_layout="paged", page_size=8), 2)
+    assert eng.paged_leaves == dict(zip(("in_place", "gathered"), counts))
+    rep = Scheduler(eng).serve([Request(np.arange(1, 5, dtype=np.int32),
+                                        max_new_tokens=2)])
+    assert (rep["paged_in_place"], rep["paged_gathered"]) == counts
+    dcfg = DrafterConfig(n_layers=1, k_infer=2).resolve(tcfg)
+    spec = Engine(tcfg, dcfg, tparams,
+                  D.init_params(dcfg, tcfg, jax.random.fold_in(KEY, 3)),
+                  EngineConfig(K=2, drafter_mode="parallel", max_len=32,
+                               kv_layout="paged", page_size=8), 2)
+    assert spec.paged_leaves == {"in_place": counts[0], "gathered": 3}
+    contiguous = Engine(tcfg, None, tparams, None,
+                        EngineConfig(K=0, drafter_mode="none", max_len=32),
+                        2)
+    assert contiguous.paged_leaves == {"in_place": 0, "gathered": 0}
 
 
 def test_bucketed_prefill_ring_window_safe():

@@ -22,7 +22,7 @@ from repro.data import MTPPipeline, markov_corpus
 from repro.kernels import ops
 from repro.models import get_model
 from repro.optim import adamw_init
-from repro.serving.engine import (EngineConfig, make_decode_state,
+from repro.serving.engine import (Engine, EngineConfig, make_decode_state,
                                   speculative_step)
 from repro.training import TrainConfig, make_train_step
 
@@ -120,6 +120,29 @@ def test_greedy_speculative_step_fits_one_v5e(one_chip):
     mem = jax.jit(step).lower(*args).compile().memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < V5E_HBM_BYTES
+
+
+def test_paged_decode_step_never_builds_the_view_on_v5e(one_chip):
+    """The benchmark's paged decode step at full width (16 slots, max_len
+    1552, page 16, no drafter) takes the target's pools donated and reads
+    and writes them in place: they alias the step's output, and its
+    temporaries stay under a quarter of one contiguous view of the cache
+    (16 slots x 1552 positions x 28 layers x K and V x 2 KV heads x 128,
+    float32), so no view is ever built."""
+    tcfg, model, key = _full_width()
+    slots, max_len = 16, 1552
+    eng = Engine(tcfg, None, jax.eval_shape(model.init, key), None,
+                 EngineConfig(drafter_mode="none", max_len=max_len,
+                              kv_layout="paged", page_size=PAGE), slots)
+    pools, rest = eng.split_pools(jax.eval_shape(eng.blank_state))
+    row = lambda dt: jax.ShapeDtypeStruct((slots,), dt)   # noqa: E731
+    args = _on(one_chip, (eng.tparams, None, pools, rest, row(jnp.bool_),
+                          row(jnp.int32), row(jnp.int32)))
+    mem = eng._paged_step[True].lower(*args).compile().memory_analysis()
+    view = (slots * max_len * tcfg.n_layers * 2 * tcfg.n_kv_heads
+            * tcfg.head_dim * 4)
+    assert mem.alias_size_in_bytes >= view        # K and V pools donated
+    assert mem.temp_size_in_bytes < view // 4
 
 
 def test_donated_train_step_fits_one_v5e(one_chip):

@@ -95,8 +95,9 @@ def test_decode_step_and_row_setter_lower_to_named_modules():
     eng = engine()
     state = eng.blank_state()
     B = eng.batch
-    args = (eng.tparams, eng.dparams, state, jnp.ones((B,), bool),
-            jnp.full((B,), 8, jnp.int32), jnp.full((B,), K, jnp.int32))
+    args = (eng.tparams, eng.dparams, *eng.split_pools(state),
+            jnp.ones((B,), bool), jnp.full((B,), 8, jnp.int32),
+            jnp.full((B,), K, jnp.int32))
     for greedy in (False, True):
         lowered = eng._paged_step[greedy].lower(*args)
         assert lowered.as_text().startswith("module @jit__paged_step_impl")
