@@ -19,7 +19,6 @@ one sequence at a time, padded to one length so one program serves all.
 """
 from __future__ import annotations
 
-import importlib
 from typing import List, Sequence, Tuple
 
 import jax
@@ -28,11 +27,6 @@ import numpy as np
 
 HEAD_ROWS = 256            # logits rows computed at once
 E4M3_MAX = 448.0
-
-
-def load_reference(name: str):
-    """The reference module ``bench/refs/<name>.py``."""
-    return importlib.import_module(f"bench.refs.{name}")
 
 
 def as_f32(x):

@@ -1,14 +1,17 @@
 """Operations and bytes that the algorithm needs, computed from shapes.
 
-One function per model family, read from the configuration file's sizes
-(``bench/configs/<config>.json``), never from the program. Bytes are those
-of the arrays as they are: the caller passes the weights' ``nbytes`` and
-the cache's item size, so a change of dtype moves the count together with
-the time. Counted: every matrix multiplication (2 operations per
-multiply-add), attention over the keys each query really attends, the
-Mamba-2 recurrence. Not counted: norms, activations, softmax, rotary
-embeddings and other elementwise work, a small share that only lowers a
-roofline or utilisation share.
+Read from the configuration file's sizes (``bench/configs/<config>.json``),
+never from the program. What differs by model family is the family
+module's (``bench/families/<family>.py``: ``flops`` and ``decode_bytes``);
+callers give each sequence as ``(past, new)``, ``new`` positions after
+``past`` cached ones, so a family counts the (query, key) pairs its own
+layers attend. Bytes are those of the arrays as they are: the caller
+passes the weights' ``nbytes`` and the cache's item size, so a change of
+dtype moves the count together with the time. Counted: every matrix
+multiplication (2 operations per multiply-add), attention over the keys
+each query really attends, a recurrence's state update. Not counted:
+norms, activations, softmax, rotary embeddings and other elementwise work,
+a small share that only lowers a roofline or utilisation share.
 """
 from __future__ import annotations
 
@@ -23,67 +26,23 @@ DTYPE_BYTES = {
 }
 
 
-# ---------------------------------------------------------------- families
-
-def transformer_layer_matmul_params(c: dict) -> int:
-    d, h, kv, hd, f = (c["hidden_size"], c["num_attention_heads"],
-                       c["num_key_value_heads"], c["head_dim"],
-                       c["intermediate_size"])
-    return d * h * hd + 2 * d * kv * hd + h * hd * d + 3 * d * f
+def target_flops(family, c: dict, seqs, head_rows: int) -> float:
+    """The target's forward over sequences ``seqs``, each ``(past, new)``,
+    with ``head_rows`` LM-head rows each."""
+    return sum(family.flops(c, past, new, head_rows) for past, new in seqs)
 
 
-def transformer_flops(c: dict, tokens: int, attended: int,
-                      head_rows: int) -> float:
-    """Forward of ``tokens`` positions through every layer, ``attended``
-    (query, key) pairs summed over those positions, and the LM head over
-    ``head_rows`` positions."""
-    L = c["num_hidden_layers"]
-    h, hd = c["num_attention_heads"], c["head_dim"]
-    return (2.0 * L * transformer_layer_matmul_params(c) * tokens
-            + 4.0 * L * h * hd * attended
-            + 2.0 * c["hidden_size"] * c["vocab_size"] * head_rows)
+def decode_iteration(family, c: dict, live: list, weight_bytes: int,
+                     cache_itemsize: int) -> tuple:
+    """(operations, bytes) one decode iteration needs over the active slots,
+    whose committed lengths are ``live``: the target's forward over one new
+    position per slot after its committed ones, and one LM-head row each;
+    the bytes the family reads and writes for it."""
+    flops = target_flops(family, c, [(n, 1) for n in live], 1)
+    return flops, family.decode_bytes(c, live, weight_bytes, cache_itemsize)
 
 
-def transformer_kv_bytes_per_position(c: dict, itemsize: int) -> int:
-    return (c["num_hidden_layers"] * 2 * c["num_key_value_heads"]
-            * c["head_dim"] * itemsize)
-
-
-def mamba2_dims(c: dict):
-    d_inner = c["expand"] * c["hidden_size"]
-    heads = d_inner // c["head_dim"]
-    return d_inner, heads, c["head_dim"], c["state_size"]
-
-
-def mamba2_flops(c: dict, tokens: int, head_rows: int) -> float:
-    """Forward of ``tokens`` positions through every SSD layer, token by
-    token (the recurrence; the chunked form of a prefill needs no fewer
-    useful operations), plus the LM head over ``head_rows`` positions."""
-    d = c["hidden_size"]
-    d_inner, H, P, N = mamba2_dims(c)
-    in_proj = 2 * d * (2 * d_inner + 2 * N + H)
-    conv = 2 * c["conv_kernel"] * (d_inner + 2 * N)
-    ssm = 2 * H * P * N * 3          # decay, dt*B*x update, C . state
-    out_proj = 2 * d_inner * d
-    per_token = c["num_hidden_layers"] * (in_proj + conv + ssm + out_proj)
-    return per_token * tokens + 2.0 * d * c["vocab_size"] * head_rows
-
-
-def mamba2_state_bytes(c: dict, conv_itemsize: int) -> int:
-    """One slot's recurrent state: float32 SSM state plus the conv window."""
-    d_inner, H, P, N = mamba2_dims(c)
-    return c["num_hidden_layers"] * (
-        H * P * N * 4 + (c["conv_kernel"] - 1) * (d_inner + 2 * N)
-        * conv_itemsize)
-
-
-def target_flops(c: dict, tokens: int, attended: int, head_rows: int) -> float:
-    if c["family"] == "dense":
-        return transformer_flops(c, tokens, attended, head_rows)
-    if c["family"] == "ssm":
-        return mamba2_flops(c, tokens, head_rows)
-    raise ValueError(f"no FLOP count for family {c['family']!r}")
-
+# ---------------------------------------------------------------- drafter
 
 def drafter_shape(c: dict, drafter: dict) -> dict:
     """The drafter as a transformer configuration: its own width rules
@@ -99,35 +58,14 @@ def drafter_shape(c: dict, drafter: dict) -> dict:
 
 def drafter_flops(dc: dict, tokens: int, attended: int,
                   head_rows: int) -> float:
-    """Drafter forward: tap projection and fusion per position, its blocks,
-    and its LM head over ``head_rows`` positions."""
-    d = dc["hidden_size"]
+    """Drafter forward: tap projection and fusion per position, its blocks
+    (a dense transformer's: attention and gated MLP matmuls, and the
+    ``attended`` (query, key) pairs its MTP mask admits), and its LM head
+    over ``head_rows`` positions."""
+    d, L = dc["hidden_size"], dc["num_hidden_layers"]
+    h, kv, hd, f = (dc["num_attention_heads"], dc["num_key_value_heads"],
+                    dc["head_dim"], dc["intermediate_size"])
+    layer = d * h * hd + 2 * d * kv * hd + h * hd * d + 3 * d * f
     fuse = 2.0 * (dc["taps"] * d * d + 2 * d * d) * tokens
-    return fuse + transformer_flops(dc, tokens, attended, head_rows)
-
-
-# ---------------------------------------------------------------- serving
-
-def decode_iteration(c: dict, live: list, weight_bytes: int,
-                     cache_itemsize: int, conv_itemsize: int) -> tuple:
-    """(operations, bytes) one decode iteration needs over the active slots,
-    whose committed lengths are ``live``: the target's forward over one new
-    position per slot, attending its committed positions and itself, and
-    one LM-head row each. Bytes: every weight once, each slot's live cache
-    positions read once and one new position written, and a recurrent
-    target's state read and written."""
-    B = len(live)
-    flops = target_flops(c, B, sum(n + 1 for n in live), B)
-    if c["family"] == "dense":
-        per_pos = transformer_kv_bytes_per_position(c, cache_itemsize)
-        state = 0
-    else:
-        per_pos = 0
-        state = 2 * B * mamba2_state_bytes(c, conv_itemsize)
-    nbytes = weight_bytes + per_pos * (sum(live) + B) + state
-    return flops, nbytes
-
-
-def prefill_attended(n: int) -> int:
-    """(query, key) pairs of a causal prefill of ``n`` tokens."""
-    return n * (n + 1) // 2
+    return fuse + (2.0 * L * layer * tokens + 4.0 * L * h * hd * attended
+                   + 2.0 * d * dc["vocab_size"] * head_rows)

@@ -5,7 +5,19 @@ one metric is a file of its own, found by the name ``BENCHMARK.json``
 gives it:
 
   bench/configs/<config>.json   the configuration's sizes as run, with its
-                                source, its cuts and its reference's name
+                                source, its cuts, its family's and its
+                                reference's names
+  bench/families/<family>.py    what the harness knows of one model family:
+                                ``program_pairs(cfg, tcfg)`` (file key ->
+                                the program's value, checked before any
+                                device work), ``flops(c, past, new,
+                                head_rows)`` (one sequence's forward over
+                                ``new`` positions after ``past`` cached
+                                ones) and ``decode_bytes(c, live,
+                                weight_bytes, cache_itemsize)`` (one
+                                decode iteration);
+                                optionally ``weight(name, key, shape,
+                                dtype)`` for leaves of its own layers
   bench/refs/<reference>.py     the plain reference of that family
   bench/traffic/<traffic>.json  the traffic mix: parameters of the one
                                 generator (bench/traffic.py) and the kind
@@ -15,8 +27,10 @@ gives it:
                                 a number, or None where the run has nothing
                                 for it to read
 
-A cell, configuration, mix or metric is added by adding such files and
-entries, without editing any file that is there.
+A cell, configuration, family, mix or metric is added by adding such
+files and entries, without editing any file that is there. Family and
+reference modules, like metric readers, are loaded from the cell's own
+``bench`` directory; a missing one fails before any device work.
 """
 from __future__ import annotations
 
@@ -64,6 +78,17 @@ class Cell:
         self.mix = load_json(bench_dir / "traffic"
                              / f"{self.entry['traffic']}.json")
         self.check = load_json(bench_dir / "cells" / f"{workload}.json")
+        self.family = load_family(self.config, bench_dir)
+        _need(bench_dir / "refs" / f"{self.config['reference']}.py",
+              "reference", f"configuration {self.entry['config']!r}")
+        if "reference" in self.mix:
+            _need(bench_dir / "refs" / f"{self.mix['reference']}.py",
+                  "reference", f"traffic mix {self.entry['traffic']!r}")
+
+    def reference(self, name: str):
+        """The plain reference module ``<bench>/refs/<name>.py``."""
+        return load_module(self.bench_dir / "refs" / f"{name}.py",
+                           f"bench_ref_{name}")
 
     def metrics(self, traced: bool) -> List[dict]:
         """The cell's end-to-end metrics (untraced) or per-layer metrics
@@ -81,6 +106,32 @@ class Cell:
         """The runner module of the mix's ``kind`` (``bench/<kind>.py``)."""
         import importlib
         return importlib.import_module(f"bench.{self.mix['kind']}")
+
+
+def _need(path: Path, what: str, owner: str) -> Path:
+    if not path.is_file():
+        raise FileNotFoundError(f"{owner} names a {what} with no module: "
+                                f"{path} is missing")
+    return path
+
+
+def load_family(config: dict, bench_dir: Path = BENCH_DIR):
+    """The family module ``<bench>/families/<family>.py`` of a
+    configuration file."""
+    name = config["family"]
+    path = _need(bench_dir / "families" / f"{name}.py", "family",
+                 f"configuration {config['name']!r}")
+    return load_module(path, f"bench_family_{name}")
+
+
+def check_config(family, cfg: dict, tcfg) -> None:
+    """The program's configuration must be the file's, size for size: the
+    family's keys and the served dtype."""
+    pairs = dict(family.program_pairs(cfg, tcfg), dtype=tcfg.dtype)
+    bad = {k: (cfg[k], v) for k, v in pairs.items() if cfg[k] != v}
+    if bad:
+        raise ValueError(f"the program's {cfg['program_arch']} differs from "
+                         f"the configuration file: {bad}")
 
 
 def seed32(seed: int, salt: int = 0) -> int:

@@ -68,7 +68,6 @@ class ServeRun:
     peak: object
     weight_bytes: int
     cache_itemsize: int
-    conv_itemsize: int
     checks: list = field(default_factory=list)
     trace: Optional[object] = None
     trace_window: Optional[tuple] = None
@@ -105,8 +104,8 @@ class ServeRun:
         for t, live in self.iter_log:
             if t0 <= t < t1 and live:
                 fl, nb = F.decode_iteration(
-                    self.config, live, self.weight_bytes,
-                    self.cache_itemsize, self.conv_itemsize)
+                    self.cell.family, self.config, live, self.weight_bytes,
+                    self.cache_itemsize)
                 bounds.append(max(fl / self.peak.flops,
                                   nb / self.peak.hbm_bytes_s))
         return sum(bounds) / len(bounds) if bounds else None
@@ -115,51 +114,18 @@ class ServeRun:
         """Target forward operations of the prompts admitted and the output
         tokens committed in [t0, t1): a prompt of P tokens is one causal
         forward with one LM-head row (its first output token); output token
-        j >= 1 is one position attending P + j keys."""
+        j >= 1 is one position after P + j - 1 cached ones."""
+        fam, c = self.cell.family, self.config
         total = 0.0
         for r in self.records:
             P = int(r.prompt.size)
             if t0 <= r.t_admit < t1:
-                total += F.target_flops(self.config, P,
-                                        F.prefill_attended(P), 1)
+                total += fam.flops(c, 0, P, 1)
             lo = max(int(np.searchsorted(r.times, t0)), 1)
             hi = int(np.searchsorted(r.times, t1))
             for j in range(lo, hi):
-                total += F.target_flops(self.config, 1, P + j, 1)
+                total += fam.flops(c, P + j - 1, 1, 1)
         return total
-
-
-def check_config(cfg: dict, tcfg) -> None:
-    """The program's configuration must be the file's, size for size."""
-    if cfg["family"] == "dense":
-        pairs = {"hidden_size": tcfg.d_model,
-                 "num_hidden_layers": tcfg.n_layers,
-                 "num_attention_heads": tcfg.n_heads,
-                 "num_key_value_heads": tcfg.n_kv_heads,
-                 "head_dim": tcfg.head_dim,
-                 "intermediate_size": tcfg.d_ff,
-                 "vocab_size": tcfg.vocab_size,
-                 "rope_theta": tcfg.rope_theta,
-                 "rms_norm_eps": tcfg.norm_eps,
-                 "tie_word_embeddings": tcfg.tie_embeddings,
-                 "attention_bias": tcfg.qkv_bias}
-    elif cfg["family"] == "ssm":
-        pairs = {"hidden_size": tcfg.d_model,
-                 "num_hidden_layers": tcfg.n_layers,
-                 "state_size": tcfg.ssm.d_state,
-                 "expand": tcfg.ssm.expand,
-                 "head_dim": tcfg.ssm.head_dim,
-                 "conv_kernel": tcfg.ssm.conv_width,
-                 "vocab_size": tcfg.vocab_size,
-                 "layer_norm_epsilon": tcfg.norm_eps,
-                 "tie_word_embeddings": tcfg.tie_embeddings}
-    else:
-        raise ValueError(f"unknown family {cfg['family']!r}")
-    pairs["dtype"] = tcfg.dtype
-    bad = {k: (cfg[k], v) for k, v in pairs.items() if cfg[k] != v}
-    if bad:
-        raise ValueError(f"the program's {cfg['program_arch']} differs from "
-                         f"the configuration file: {bad}")
 
 
 def build(cell, seed: int):
@@ -174,7 +140,7 @@ def build(cell, seed: int):
 
     cfg, mix = cell.config, cell.mix
     tcfg = get_config(cfg["program_arch"])
-    check_config(cfg, tcfg)
+    H.check_config(cell.family, cfg, tcfg)
     e = mix["engine"]
     if e["drafter_mode"] != "none":
         raise ValueError(f"drafter_mode {e['drafter_mode']!r}: the program "
@@ -183,7 +149,7 @@ def build(cell, seed: int):
     key = jax.random.PRNGKey(H.seed32(seed))
     model = get_model(tcfg)
     tparams = weights.make(jax.eval_shape(model.init, key),
-                           jax.random.fold_in(key, 1))
+                           jax.random.fold_in(key, 1), cell.family)
     ecfg = EngineConfig(drafter_mode="none", max_len=e["max_len"],
                         kv_layout="paged", page_size=e["page_size"])
     eng = build_engine(tcfg, tparams, ecfg, e["slots"])
@@ -342,7 +308,7 @@ def run(cell, seed: int, seconds: float, traced: bool, t_start: float,
                  records=out["records"], window=out["window"],
                  report=out["report"], device=device, peak=peak,
                  weight_bytes=wbytes, cache_itemsize=cache_itemsize,
-                 conv_itemsize=cache_itemsize, iter_log=out["iter_log"],
+                 iter_log=out["iter_log"],
                  trace_window=out["trace_window"])
     due = r.due_in_window()
     r.attempted = len(due)
@@ -380,7 +346,7 @@ def check(r: ServeRun, tparams, seed: int, control: bool = False) -> list:
                               limits["widest_gap"]))
         r.control_checks = list(checks) if control else None
         return checks
-    ref = C.load_reference(r.config["reference"])
+    ref = r.cell.reference(r.config["reference"])
     t0 = H.now()
     gaps = C.served_gaps(ref, r.config, tparams, [pairs[i] for i in pick],
                          r.mix["engine"]["max_len"])

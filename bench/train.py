@@ -57,8 +57,7 @@ class TrainRun:
         """Target forward (no LM head: training reads only its taps) and
         drafter forward and backward (3x forward) per step."""
         B, S = self.mix["batch"], self.mix["seq"]
-        tgt = F.target_flops(self.config, B * S,
-                             B * F.prefill_attended(S), 0)
+        tgt = F.target_flops(self.cell.family, self.config, [(0, S)] * B, 0)
         drf = F.drafter_flops(self.drafter, B * self.valid,
                               B * self.attended, B * self.valid)
         return tgt + 3.0 * drf
@@ -123,12 +122,11 @@ def run(cell, seed: int, seconds: float, traced: bool, t_start: float,
 
     from bench import weights
     from bench.peaks import peak_for
-    from bench.serve import check_config
 
     cfg, mix = cell.config, cell.mix
     peak = peak_for(devices[0].device_kind)
     tcfg = get_config(cfg["program_arch"])
-    check_config(cfg, tcfg)
+    H.check_config(cell.family, cfg, tcfg)
     dshape = dict(F.drafter_shape(cfg, mix["drafter"]), **mix["drafter"])
     dcfg = DrafterConfig(n_layers=mix["drafter"]["layers"],
                          k_train=mix["k_train"], cod_rate=mix["cod_rate"]
@@ -144,7 +142,7 @@ def run(cell, seed: int, seconds: float, traced: bool, t_start: float,
     key = jax.random.PRNGKey(H.seed32(seed))
     model = get_model(tcfg)
     tparams = weights.make(jax.eval_shape(model.init, key),
-                           jax.random.fold_in(key, 1))
+                           jax.random.fold_in(key, 1), cell.family)
     dabs = jax.eval_shape(lambda k: D.init_params(dcfg, tcfg, k), key)
     dkey = jax.random.fold_in(key, 2)
     opt = mix["optimizer"]
@@ -249,11 +247,10 @@ def reference_steps(r: TrainRun, tparams, dabs, dkey, batches, dtype):
     import jax
     import jax.numpy as jnp
     from bench import weights
-    from bench.correct import load_reference
 
     cfg, mix = r.config, r.mix
-    tref = load_reference(cfg["reference"])
-    dref = load_reference(mix["reference"])
+    tref = r.cell.reference(cfg["reference"])
+    dref = r.cell.reference(mix["reference"])
     opt = mix["optimizer"]
     vocab = cfg["vocab_size"]
     d = r.drafter

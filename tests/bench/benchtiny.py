@@ -26,27 +26,17 @@ TINY_MIX = {
 
 
 def tiny_config(arch: str) -> dict:
+    """The configuration file of ``arch`` at the program's reduced sizes:
+    its family's keys (``program_pairs``) with the reduced values."""
     from repro.configs import get_config
+
+    from bench import harness as H
+    full = H.load_json(BENCH / "configs" / f"{arch}.json")
     t = get_config(arch).reduced()
-    if t.family == "ssm":
-        sizes = {"family": "ssm", "reference": "mamba2",
-                 "hidden_size": t.d_model, "num_hidden_layers": t.n_layers,
-                 "state_size": t.ssm.d_state, "expand": t.ssm.expand,
-                 "head_dim": t.ssm.head_dim, "conv_kernel": t.ssm.conv_width,
-                 "n_groups": 1, "layer_norm_epsilon": t.norm_eps}
-    else:
-        sizes = {"family": "dense", "reference": "transformer",
-                 "hidden_size": t.d_model,
-                 "intermediate_size": t.d_ff,
-                 "num_attention_heads": t.n_heads,
-                 "num_key_value_heads": t.n_kv_heads,
-                 "num_hidden_layers": t.n_layers, "head_dim": t.head_dim,
-                 "rope_theta": t.rope_theta, "rms_norm_eps": t.norm_eps,
-                 "attention_bias": t.qkv_bias}
     return {"name": f"tiny-{arch}", "source": "reduced", "program_arch": arch,
-            "reduced": [], "vocab_size": t.vocab_size,
-            "tie_word_embeddings": t.tie_embeddings, "dtype": t.dtype,
-            **sizes}
+            "family": full["family"], "reference": full["reference"],
+            "reduced": [], "dtype": t.dtype,
+            **H.load_family(full).program_pairs(full, t)}
 
 
 def make_bench(tmp: Path, arch: str, limits=None, mix=None):
